@@ -1,44 +1,25 @@
-"""Version-compat shims for the small set of JAX APIs whose spelling moved.
+"""Mesh and shard_map construction in one spelling.
 
-The repo is written against the current JAX surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``check_vma``); older jaxlibs in the 0.4.x series
-spell these ``jax.experimental.shard_map.shard_map``, no axis types, and
-``check_rep``.  Everything that builds a mesh or wraps a function in
-shard_map goes through this module so the rest of the codebase can use one
-spelling.
+Everything that builds a mesh or wraps a function in shard_map goes through
+this module, so every mesh gets explicit ``Auto`` axis types and every
+shard_map the same ``check_vma`` default.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - exercised only on old jaxlibs
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AxisType is not None:
-        return jax.make_mesh(
-            shape, axis_names, axis_types=(_AxisType.Auto,) * len(axis_names)
-        )
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(
+        shape, axis_names, axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across the rename from ``check_rep`` to ``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+    """``jax.shard_map`` with ``check_vma`` off unless asked for."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 __all__ = ["make_mesh", "shard_map"]

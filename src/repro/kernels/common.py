@@ -1,42 +1,10 @@
-"""Shared kernel utilities: interpret-mode selection, tiling helpers, and
-cross-version shims for the remote-DMA primitives (the kernel-level
-counterpart of ``repro.compat``)."""
+"""Shared kernel utilities: interpret-mode selection, the accumulate op
+table, and tiling helpers."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
-
-_JAX_VERSION = tuple(int(x) for x in jax.__version__.split(".")[:2])
-
-
-def remote_device_id(target):
-    """``make_async_remote_copy`` device-id across pallas versions.
-
-    Newer pallas accepts (and documents) a tuple of mesh coordinates; the
-    0.4.x interpreter's discharge rule chokes on tuples and needs the raw
-    scalar.  All kernels here run on 1-D meshes, so the two are equivalent.
-    """
-    return target if _JAX_VERSION < (0, 5) else (target,)
-
-
-def sync_copy(src_ref, dst_ref, sem=None):
-    """Blocking local copy between refs (HBM/ANY <-> VMEM staging).
-
-    ``pltpu.sync_copy`` where available; older pallas has no synchronous
-    primitive, so the caller must lend a DMA semaphore (allocate one spare
-    in ``scratch_shapes``) and we issue start+wait on it.
-    """
-    if hasattr(pltpu, "sync_copy"):
-        pltpu.sync_copy(src_ref, dst_ref)
-        return
-    if sem is None:
-        raise ValueError(
-            "this pallas version has no sync_copy; pass a spare DMA "
-            "semaphore (add one to the kernel's scratch_shapes)")
-    cp = pltpu.make_async_copy(src_ref, dst_ref, sem)
-    cp.start()
-    cp.wait()
 
 
 def interpret_mode():
@@ -50,11 +18,8 @@ def interpret_mode():
         return False
     # eager DMA execution models hardware (transfers land when posted);
     # the default "on_wait" defers execution to the wait and breaks
-    # multi-hop ring schedules.  Older Pallas releases predate
-    # InterpretParams and only offer the boolean interpreter.
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams(dma_execution_mode="eager")
-    return True
+    # multi-hop ring schedules
+    return pltpu.InterpretParams(dma_execution_mode="eager")
 
 
 #: Ops the NIC-atomic-style kernels implement — the accumulate subset of the
@@ -94,5 +59,5 @@ def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
 
 
-__all__ = ["interpret_mode", "cdiv", "round_up", "remote_device_id",
-           "sync_copy", "combine_op", "ATOMIC_KERNEL_OPS"]
+__all__ = ["interpret_mode", "cdiv", "round_up", "combine_op",
+           "ATOMIC_KERNEL_OPS"]

@@ -30,30 +30,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (ATOMIC_KERNEL_OPS, combine_op,
-                                  interpret_mode, remote_device_id, sync_copy)
+                                  interpret_mode)
 
 
 def _acc_kernel(x_ref, buf_ref, o_ref, stage_ref, cur_vmem, in_vmem,
-                send_sem, recv_sem, copy_sem, *, axis: str, shift: int,
+                send_sem, recv_sem, *, axis: str, shift: int,
                 axis_size: int, offset: int, op: str):
     my = jax.lax.axis_index(axis)
     target = jax.lax.rem(my + shift + axis_size, axis_size)
     # carry the window buffer through to the output before the atomic lands
-    sync_copy(buf_ref, o_ref, copy_sem)
+    pltpu.sync_copy(buf_ref, o_ref)
     # one remote DMA: my update into the target's staging slot
     rdma = pltpu.make_async_remote_copy(
         x_ref, stage_ref, send_sem, recv_sem,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     rdma.start()
     rdma.wait()  # send retired + my own incoming update staged
     # target side of the atomic: fold the staged update into the buffer
     # (HBM/ANY refs are DMA-only: stage through VMEM for the VPU op)
     n = x_ref.shape[0]
-    sync_copy(o_ref.at[pl.ds(offset, n)], cur_vmem, copy_sem)
-    sync_copy(stage_ref, in_vmem, copy_sem)
+    pltpu.sync_copy(o_ref.at[pl.ds(offset, n)], cur_vmem)
+    pltpu.sync_copy(stage_ref, in_vmem)
     cur_vmem[...] = combine_op(cur_vmem[...], in_vmem[...].astype(cur_vmem.dtype), op)
-    sync_copy(cur_vmem, o_ref.at[pl.ds(offset, n)], copy_sem)
+    pltpu.sync_copy(cur_vmem, o_ref.at[pl.ds(offset, n)])
 
 
 def ring_accumulate(update, buffer, *, axis: str, axis_size: int,
@@ -100,8 +100,7 @@ def ring_accumulate(update, buffer, *, axis: str, axis_size: int,
                    jax.ShapeDtypeStruct(update.shape, update.dtype)],
         scratch_shapes=[pltpu.VMEM(update.shape, buffer.dtype),
                         pltpu.VMEM(update.shape, update.dtype),
-                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA],
+                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         interpret=interpret_mode(),
     )(update, buffer)
     return out

@@ -27,7 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (ATOMIC_KERNEL_OPS, combine_op,
-                                  interpret_mode, remote_device_id, sync_copy)
+                                  interpret_mode)
 
 
 def _put_signal_kernel(x_ref, flag_ref, o_ref, oflag_ref,
@@ -37,7 +37,7 @@ def _put_signal_kernel(x_ref, flag_ref, o_ref, oflag_ref,
     target = jax.lax.rem(my + shift + axis_size, axis_size)
     data = pltpu.make_async_remote_copy(
         x_ref, o_ref, dsend, drecv,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     data.start()
     if ordered:
@@ -49,7 +49,7 @@ def _put_signal_kernel(x_ref, flag_ref, o_ref, oflag_ref,
         data.wait()
     flag = pltpu.make_async_remote_copy(
         flag_ref, oflag_ref, fsend, frecv,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     flag.start()
     flag.wait()
@@ -84,15 +84,15 @@ def put_signal(x, flag, *, axis: str, axis_size: int, shift: int = 1,
 
 
 def _acc_signal_kernel(x_ref, buf_ref, flag_ref, o_ref, stage_ref, oflag_ref,
-                       cur_vmem, in_vmem, dsend, drecv, fsend, frecv,
-                       copy_sem, *, axis: str, shift: int, axis_size: int,
+                       cur_vmem, in_vmem, dsend, drecv, fsend, frecv, *,
+                       axis: str, shift: int, axis_size: int,
                        offset: int, op: str, ordered: bool):
     my = jax.lax.axis_index(axis)
     target = jax.lax.rem(my + shift + axis_size, axis_size)
-    sync_copy(buf_ref, o_ref, copy_sem)
+    pltpu.sync_copy(buf_ref, o_ref)
     data = pltpu.make_async_remote_copy(
         x_ref, stage_ref, dsend, drecv,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     data.start()
     if ordered:
@@ -104,7 +104,7 @@ def _acc_signal_kernel(x_ref, buf_ref, flag_ref, o_ref, stage_ref, oflag_ref,
         data.wait()
     flag = pltpu.make_async_remote_copy(
         flag_ref, oflag_ref, fsend, frecv,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     flag.start()
     if ordered:
@@ -112,11 +112,11 @@ def _acc_signal_kernel(x_ref, buf_ref, flag_ref, o_ref, stage_ref, oflag_ref,
     # target side: fold the staged update into the window buffer before the
     # kernel exits — a consumer observing the flag sees the applied update
     n = x_ref.shape[0]
-    sync_copy(o_ref.at[pl.ds(offset, n)], cur_vmem, copy_sem)
-    sync_copy(stage_ref, in_vmem, copy_sem)
+    pltpu.sync_copy(o_ref.at[pl.ds(offset, n)], cur_vmem)
+    pltpu.sync_copy(stage_ref, in_vmem)
     cur_vmem[...] = combine_op(cur_vmem[...],
                                in_vmem[...].astype(cur_vmem.dtype), op)
-    sync_copy(cur_vmem, o_ref.at[pl.ds(offset, n)], copy_sem)
+    pltpu.sync_copy(cur_vmem, o_ref.at[pl.ds(offset, n)])
     flag.wait()
 
 
@@ -157,8 +157,7 @@ def accumulate_signal(update, buffer, flag, *, axis: str, axis_size: int,
         scratch_shapes=[pltpu.VMEM(update.shape, buffer.dtype),
                         pltpu.VMEM(update.shape, update.dtype),
                         pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA],
+                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         interpret=interpret_mode(),
     )(update, buffer, flag)
     return out, oflag

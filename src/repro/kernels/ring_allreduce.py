@@ -19,11 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_mode, remote_device_id, sync_copy
+from repro.kernels.common import interpret_mode
 
 
 def _ar_kernel(x_ref, o_ref, recv_ref, acc_vmem, in_vmem, send_sem, recv_sem,
-               credit_sem, copy_sem, *, axis: str, axis_size: int):
+               credit_sem, *, axis: str, axis_size: int):
     n = axis_size
     my = jax.lax.axis_index(axis)
     nxt = jax.lax.rem(my + 1, n)
@@ -46,23 +46,23 @@ def _ar_kernel(x_ref, o_ref, recv_ref, acc_vmem, in_vmem, send_sem, recv_sem,
         # send my current partial of chunk send_idx into neighbour's recv slot
         rdma = pltpu.make_async_remote_copy(
             o_ref.at[send_idx], recv_ref.at[slot], send_sem, recv_sem,
-            device_id=remote_device_id(nxt),
+            device_id=(nxt,),
             device_id_type=pltpu.DeviceIdType.MESH)
         rdma.start()
         rdma.wait()
         # accumulate the incoming partial into my chunk recv_idx
         # (HBM/ANY refs are DMA-only: stage through VMEM for the VPU add)
-        sync_copy(o_ref.at[recv_idx], acc_vmem, copy_sem)
-        sync_copy(recv_ref.at[slot], in_vmem, copy_sem)
+        pltpu.sync_copy(o_ref.at[recv_idx], acc_vmem)
+        pltpu.sync_copy(recv_ref.at[slot], in_vmem)
         acc_vmem[...] = acc_vmem[...] + in_vmem[...]
-        sync_copy(acc_vmem, o_ref.at[recv_idx], copy_sem)
+        pltpu.sync_copy(acc_vmem, o_ref.at[recv_idx])
         # slot drained: credit my upstream so it may overwrite it
         pltpu.semaphore_signal(credit_sem, 1, device_id=prv,
                                device_id_type=pltpu.DeviceIdType.MESH)
         return 0
 
     # initialize output with my own contribution
-    sync_copy(x_ref, o_ref, copy_sem)
+    pltpu.sync_copy(x_ref, o_ref)
     jax.lax.fori_loop(0, n - 1, rs_body, 0)
     # drain outstanding credits so the semaphore ends at zero
     pltpu.semaphore_wait(credit_sem, 2 if n > 2 else 1)
@@ -73,7 +73,7 @@ def _ar_kernel(x_ref, o_ref, recv_ref, acc_vmem, in_vmem, send_sem, recv_sem,
         send_idx = jax.lax.rem(my + 1 - i + n * 8, n)
         rdma = pltpu.make_async_remote_copy(
             o_ref.at[send_idx], o_ref.at[send_idx], send_sem, recv_sem,
-            device_id=remote_device_id(nxt),
+            device_id=(nxt,),
             device_id_type=pltpu.DeviceIdType.MESH)
         rdma.start()
         rdma.wait()
@@ -117,7 +117,7 @@ def ring_all_reduce(x, *, axis: str, axis_size: int, config=None):
         scratch_shapes=[pltpu.VMEM((chunk,) + x.shape[1:], x.dtype),
                         pltpu.VMEM((chunk,) + x.shape[1:], x.dtype),
                         pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.REGULAR, pltpu.SemaphoreType.DMA],
+                        pltpu.SemaphoreType.REGULAR],
         interpret=interpret_mode(),
     )(xview)
     out = out.reshape((-1,) + x.shape[1:])

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_mode, remote_device_id
+from repro.kernels.common import interpret_mode
 
 
 def _put_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis: str, shift: int,
@@ -32,7 +32,7 @@ def _put_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis: str, shift: int,
     target = jax.lax.rem(my + shift + axis_size, axis_size)
     rdma = pltpu.make_async_remote_copy(
         x_ref, o_ref, send_sem, recv_sem,
-        device_id=remote_device_id(target),
+        device_id=(target,),
         device_id_type=pltpu.DeviceIdType.MESH)
     rdma.start()
     rdma.wait()  # thread-scope flush: this stream's semaphores only

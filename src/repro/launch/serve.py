@@ -3,21 +3,14 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --tiny \
       --requests 8 --max-new 16
 
-Disaggregated mode (the RMA serving data plane, ``docs/serving_disagg.md``):
-
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --disagg
-
-runs the decode engine on the **paged KV pool** (page-table indirection,
-page alloc/free at slot admit/release) and first drives the 8-fake-device
-prefill→push→doorbell→admission→decode round trip through memory handles in
-a subprocess.  ``--disagg --dry-run`` runs only that round trip.
+``--disagg`` serves on the **paged KV pool** of the disaggregated runtime
+(page-table indirection, page alloc/free at slot admit/release;
+``docs/serving_disagg.md``).  The prefill→push→doorbell→admission→decode
+round trip itself runs across devices: ``python -m repro.serve.disagg``.
 """
 from __future__ import annotations
 
 import argparse
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -25,35 +18,9 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.tiny import tiny_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serve.engine import Request, ServeEngine
-
-
-def run_disagg_demo() -> None:
-    """The SPMD round trip needs 8 fake devices, which must be configured
-    before JAX initializes — run it as a subprocess."""
-    import repro
-
-    env = dict(os.environ)
-    # fake host devices only multiply the CPU backend: pin the subprocess to
-    # it (the demo is a semantics check, not a perf run) and keep whatever
-    # XLA flags the user already set
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = "--xla_force_host_platform_device_count=8"
-    prev_flags = env.get("XLA_FLAGS")
-    env["XLA_FLAGS"] = f"{prev_flags} {flags}" if prev_flags else flags
-    # the subprocess must import repro from wherever *this* process found it
-    # (cwd-independent — "src" only exists relative to the repo root)
-    src_root = os.path.dirname(os.path.dirname(os.path.abspath(repro.__file__)))
-    prev = env.get("PYTHONPATH")
-    env["PYTHONPATH"] = src_root + (os.pathsep + prev if prev else "")
-    proc = subprocess.run([sys.executable, "-m", "repro.serve.disagg"],
-                          env=env, capture_output=True, text=True,
-                          timeout=600)
-    print(proc.stdout, end="")
-    if proc.returncode != 0:
-        print(proc.stderr)
-        raise SystemExit("disagg round-trip demo failed")
 
 
 def main(argv=None):
@@ -68,8 +35,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--disagg", action="store_true",
-                    help="disaggregated mode: paged-KV decode engine + the "
-                         "prefill→decode handle-path round-trip demo")
+                    help="disaggregated mode: the paged-KV decode engine")
     ap.add_argument("--page-tokens", type=int, default=16,
                     help="tokens per KV page in --disagg mode")
     ap.add_argument("--policy", default="continuous",
@@ -96,19 +62,11 @@ def main(argv=None):
                     help="with --inject: decode slots are owned "
                          "n_slots//workers per worker; evicting a worker "
                          "drains and requeues its slots")
-    ap.add_argument("--dry-run", action="store_true",
-                    help="with --disagg: run only the round-trip demo")
     args = ap.parse_args(argv)
 
-    if args.dry_run and not args.disagg:
-        ap.error("--dry-run requires --disagg")
     if args.prefix_share and not args.disagg:
         ap.error("--prefix-share requires --disagg (the paged pool)")
-    if args.disagg:
-        run_disagg_demo()
-        if args.dry_run:
-            return
-
+    use_compile_cache()
     cfg = tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -1,7 +1,11 @@
 """Training launcher: data → step → checkpoint/restart → straggler watch.
 
-Runs real training on whatever devices exist (CPU for examples/tests, a TPU
-slice in production — the mesh adapts).  Fault-tolerance behaviours:
+Runs real training under ``jax.jit`` on the default device: the step is
+not sharded, so on a multi-chip host it runs on the first chip (only
+``--moe-ep=rma`` spreads the experts over every device).  The data-parallel
+RMA gradient sync across chips is built with ``make_train_step(grad_sync=
+"rma_ring")`` under ``shard_map`` (see ``chip_smoke.py --four-chips``).
+Fault-tolerance behaviours:
 
 * periodic async checkpoints (atomic, retained K);
 * ``--resume`` restores the latest complete checkpoint **and** the data
@@ -30,6 +34,7 @@ from repro.configs import get_config
 from repro.configs.tiny import tiny_config
 from repro.data.pipeline import DataConfig, make_source
 from repro.ft.straggler import StragglerMonitor
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.train.optimizer import OptimizerConfig, init_opt_state
 from repro.train.trainstep import make_train_step
@@ -49,7 +54,7 @@ def train(arch: str, *, tiny: bool = True, steps: int = 100,
           ckpt_dir: str | None = None, ckpt_every: int = 50,
           resume: bool = False, fail_at_step: int | None = None,
           peak_lr: float = 3e-3, log_every: int = 10,
-          data_seed: int = 0, mesh=None, grad_sync: str = "gspmd",
+          data_seed: int = 0, grad_sync: str = "gspmd",
           moe_ep: str | None = None) -> TrainRun:
     cfg = tiny_config(arch) if tiny else get_config(arch)
     model = build_model(cfg)
@@ -145,6 +150,7 @@ def main(argv=None):
                     help="MoE expert-parallel dispatch: partitioner all-to-all"
                          " (gspmd) or the one-sided RMA token exchange (rma)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     run = train(args.arch, tiny=args.tiny, steps=args.steps,
                 global_batch=args.global_batch, seq_len=args.seq_len,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -42,6 +42,60 @@ from repro.train.optimizer import (
 Array = jax.Array
 
 
+def make_grad_sync(*, grad_sync: str = "gspmd", data_axis: str | None = None,
+                   data_axis_size: int = 1, compressor=None, topology=None,
+                   backend: str = "rma"):
+    """The gradient sync :func:`make_train_step` applies between the
+    gradients and AdamW: ``sync_grads(grads) -> grads``.
+
+    Under ``"rma_ring"`` (inside ``shard_map`` over ``data_axis``) it
+    returns the gradients averaged over the axis; otherwise it returns them
+    unchanged (the partitioner inserts the collectives, or the caller syncs
+    compressed gradients itself)."""
+
+    def sync_grads(grads):
+        if grad_sync == "gspmd" or data_axis is None or data_axis_size == 1:
+            return grads  # partitioner-inserted collectives
+        if compressor is not None:
+            return grads  # handled at caller level with state
+        from repro.core.rma.collectives import plan_all_reduce
+        from repro.core.rma.topology import default_topology
+        from repro.core.rma.window import Window, WindowConfig
+
+        topo = (topology if topology is not None
+                else default_topology(data_axis_size))
+
+        # One window, one ring, all leaves: the whole gradient pytree is
+        # synced as a single concatenated vector, so the per-step cost is
+        # one 2(n-1)-phase ring plus one exit flush epoch — not a ring (and
+        # a flush) per leaf.  Gradient sync is a pure same-op (sum)
+        # accumulate stream, so declare it: the ring runs on a
+        # sum-specialized dup of the gradient window (paper §2.3 hints × P4
+        # dup), lowering every reduce hop through the accumulate engine's
+        # specialized path.  The exchange is a declarative-plan replay
+        # (``collectives.all_reduce_plan``): the schedule is planned once
+        # per gradient-vector shape and every subsequent step is pure
+        # issue — build-once, execute-many.
+        flat, tdef = jax.tree.flatten(grads)
+        sizes = [g.size for g in flat]
+        vec = jnp.concatenate([g.reshape(-1).astype(jnp.float32) for g in flat])
+        win = Window.allocate(
+            vec, data_axis, data_axis_size,
+            WindowConfig(scope="thread", order=True, accumulate_ops=("sum",),
+                         topology=topo))
+        sumwin = win.dup_with_info(same_op="sum")
+        vec = plan_all_reduce(vec, data_axis, data_axis_size, order=True,
+                              win=sumwin, topology=topo,
+                              backend=backend) / data_axis_size
+        out, off = [], 0
+        for g, n in zip(flat, sizes):
+            out.append(vec[off:off + n].reshape(g.shape))  # f32, as before
+            off += n
+        return jax.tree.unflatten(tdef, out)
+
+    return sync_grads
+
+
 def make_train_step(
     model,
     opt_cfg: OptimizerConfig,
@@ -116,45 +170,10 @@ def make_train_step(
         return loss_sum / accum_steps, {"xent": loss_sum / accum_steps,
                                         "aux": jnp.zeros(())}, grads
 
-    def sync_grads(grads):
-        if grad_sync == "gspmd" or data_axis is None or data_axis_size == 1:
-            return grads  # partitioner-inserted collectives
-        if compressor is not None:
-            return grads  # handled at caller level with state
-        from repro.core.rma.collectives import plan_all_reduce
-        from repro.core.rma.topology import default_topology
-        from repro.core.rma.window import Window, WindowConfig
-
-        topo = (topology if topology is not None
-                else default_topology(data_axis_size))
-
-        # One window, one ring, all leaves: the whole gradient pytree is
-        # synced as a single concatenated vector, so the per-step cost is
-        # one 2(n-1)-phase ring plus one exit flush epoch — not a ring (and
-        # a flush) per leaf.  Gradient sync is a pure same-op (sum)
-        # accumulate stream, so declare it: the ring runs on a
-        # sum-specialized dup of the gradient window (paper §2.3 hints × P4
-        # dup), lowering every reduce hop through the accumulate engine's
-        # specialized path.  The exchange is a declarative-plan replay
-        # (``collectives.all_reduce_plan``): the schedule is planned once
-        # per gradient-vector shape and every subsequent step is pure
-        # issue — build-once, execute-many.
-        flat, tdef = jax.tree.flatten(grads)
-        sizes = [g.size for g in flat]
-        vec = jnp.concatenate([g.reshape(-1).astype(jnp.float32) for g in flat])
-        win = Window.allocate(
-            vec, data_axis, data_axis_size,
-            WindowConfig(scope="thread", order=True, accumulate_ops=("sum",),
-                         topology=topo))
-        sumwin = win.dup_with_info(same_op="sum")
-        vec = plan_all_reduce(vec, data_axis, data_axis_size, order=True,
-                              win=sumwin, topology=topo,
-                              backend=backend) / data_axis_size
-        out, off = [], 0
-        for g, n in zip(flat, sizes):
-            out.append(vec[off:off + n].reshape(g.shape))  # f32, as before
-            off += n
-        return jax.tree.unflatten(tdef, out)
+    sync_grads = make_grad_sync(grad_sync=grad_sync, data_axis=data_axis,
+                                data_axis_size=data_axis_size,
+                                compressor=compressor, topology=topology,
+                                backend=backend)
 
     def train_step(params, opt_state, batch):
         loss, metrics, grads = grads_of(params, batch)
@@ -171,4 +190,4 @@ def init_train_state(model, key, opt_cfg: OptimizerConfig | None = None):
     return params, init_opt_state(params)
 
 
-__all__ = ["make_train_step", "init_train_state"]
+__all__ = ["make_train_step", "make_grad_sync", "init_train_state"]

@@ -81,13 +81,8 @@ for ordered in (True, False):
 print("accumulate_signal both orders OK")
 
 xr = jax.random.normal(jax.random.PRNGKey(0), (N*13,))
-try:
-    out = np.asarray(run(lambda s: ring_all_reduce(s, axis="x", axis_size=N), xr))
-    expect = np.tile(np.asarray(xr).reshape(N,13).sum(0), (N,1)).reshape(-1)
-    np.testing.assert_allclose(out, expect, rtol=1e-5)
-    print("ring_all_reduce OK")
-except NotImplementedError:
-    # the 0.4.x interpreter cannot discharge the remote credit signal the
-    # flow control uses; the kernel is TPU-only there
-    print("ring_all_reduce SKIPPED (interpreter lacks remote semaphore_signal)")
+out = np.asarray(run(lambda s: ring_all_reduce(s, axis="x", axis_size=N), xr))
+expect = np.tile(np.asarray(xr).reshape(N,13).sum(0), (N,1)).reshape(-1)
+np.testing.assert_allclose(out, expect, rtol=1e-5)
+print("ring_all_reduce OK")
 print("RMA KERNELS OK")

@@ -14,9 +14,10 @@ from repro import compat
 N = 8
 mesh = compat.make_mesh((N,), ("x",))
 
-def count_cp(f):
+def count_cp(f, optimized=True):
     g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x")))
-    txt = g.lower(jnp.zeros((N*4,), jnp.float32)).compile().as_text()
+    low = g.lower(jnp.zeros((N*4,), jnp.float32))
+    txt = low.compile().as_text() if optimized else low.as_text(dialect="hlo")
     return txt.count("collective-permute(")  , txt.count("collective-permute-start(")
 
 # put_signal listing1 (no order) vs listing2 (order)
@@ -170,8 +171,13 @@ def mk_ordered_get(order):
         return data
     return f
 
-g_ord = count_cp(mk_ordered_get(True))[0]
-g_unord = count_cp(mk_ordered_get(False))[0]
+# Counted before optimisation: unchained, the baseline's get request header
+# carries the same [addr, epoch] value over the same pairs as the put's
+# header word, and XLA's CSE merges the two permutes into one — an artifact
+# of the simulation (on the wire they are two packets) that would hide one
+# phase of the baseline and shrink the measured saving to 1.
+g_ord = count_cp(mk_ordered_get(True), optimized=False)[0]
+g_unord = count_cp(mk_ordered_get(False), optimized=False)[0]
 print("memhandle put->get ordered:", g_ord, " unordered baseline:", g_unord)
 assert g_ord == g_unord - 2, \
     "P2 ordering must remove the put->get intermediate flush epoch"
