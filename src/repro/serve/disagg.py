@@ -202,8 +202,6 @@ class PageAllocator:
     def __init__(self, n_pages: int):
         self.n_pages = n_pages
         self._free = list(range(n_pages))
-        self.allocs = 0
-        self.frees = 0
 
     def alloc(self, n: int) -> list[int]:
         if n > len(self._free):
@@ -211,12 +209,10 @@ class PageAllocator:
                 f"KV page pool exhausted: need {n} pages, "
                 f"{len(self._free)}/{self.n_pages} free")
         pages, self._free = self._free[:n], self._free[n:]
-        self.allocs += n
         return pages
 
     def free(self, pages) -> None:
         self._free.extend(pages)
-        self.frees += len(pages)
 
     @property
     def n_free(self) -> int:

@@ -61,6 +61,7 @@ the committed token streams are bit-identical to the all-HBM engine.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -91,6 +92,23 @@ class Completion:
     finished: bool = True       # False: run() ran out of ticks (partial)
     arrival_tick: int = 0
     done_tick: int = 0
+    # host times (time.perf_counter; 0.0 where not reached), as on SchedEntry
+    t_submit: float = 0.0
+    t_admit: float = 0.0        # its prefill dispatched
+    t_first: float = 0.0        # its first token read on the host
+    t_out: float = 0.0          # the end of the tick that made that token
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(name: str, **args):
+    """A host span in the profiler's trace, named ``serve.*``, with integer
+    ``args``.  While no trace runs it is a shared no-op, well under a
+    microsecond; call it only outside jitted code."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name, **args)
+    return _NO_SPAN
 
 
 def _paged_dicts(tree):
@@ -163,6 +181,7 @@ class Executor:
                     "stay dense) — the paged data plane would be a no-op")
             self.cache = paged_cache
         self._decode_fn = jax.jit(model.decode_step)
+        self.prefill_shapes: set[tuple] = set()   # one compile each
 
         # single-sequence prefill that scatters into one cache slot; in
         # paged mode the dense prefill KV is re-paged into the slot's
@@ -183,17 +202,23 @@ class Executor:
                 write_ok: Array) -> int:
         """Prefill one admitted request into ``slot``; returns its first
         greedy token."""
+        self.prefill_shapes.add(tuple(tokens.shape))
         logits, self.cache = self._prefill_fn(self.params, self.cache,
                                               tokens, slot, phys_pages,
                                               write_ok)
-        return int(np.asarray(jnp.argmax(logits[0, -1])))
+        argmax = jnp.argmax(logits[0, -1])
+        with _span("serve.prefill.sync"):
+            return int(np.asarray(argmax))
 
     def decode(self, last_tokens: np.ndarray) -> np.ndarray:
         """One decode step over every slot; returns per-slot argmax."""
-        logits, self.cache = self._decode_fn(
-            self.params, self.cache, jnp.asarray(last_tokens))
-        return np.asarray(jnp.argmax(logits[:, -1, :], axis=-1)
-                          .astype(jnp.int32))
+        tokens = jnp.asarray(last_tokens)
+        with _span("serve.decode"):
+            logits, self.cache = self._decode_fn(self.params, self.cache,
+                                                 tokens)
+            argmax = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            with _span("serve.decode.sync"):
+                return np.asarray(argmax)
 
     # -- paged-pool device ops ---------------------------------------------------
     def fork_page(self, slot: int, j: int, src: int, dst: int) -> None:
@@ -495,6 +520,11 @@ class ServeEngine:
         self._tick = 0
         self._incomplete = 0
         self.max_live = 0
+        # running sums over ticks of the pool's pages reserved and pages
+        # holding tokens, as each tick starts
+        self._tick_pages_reserved = 0
+        self._tick_pages_used = 0
+        self._await_out: list = []   # first token made this tick: t_out due
 
     # -- compat views ------------------------------------------------------------
     @property
@@ -514,8 +544,9 @@ class ServeEngine:
     def submit(self, req: Request) -> None:
         if len(req.prompt) >= self.max_seq:
             raise ValueError("prompt longer than max_seq")
-        self.scheduler.submit(req, tick=self._tick,
-                              t_submit=time.perf_counter())
+        with _span("serve.submit", rid=req.rid):
+            self.scheduler.submit(req, tick=self._tick,
+                                  t_submit=time.perf_counter())
 
     def step(self) -> None:
         """One engine tick: migrate tiers, admit per the policy, then one
@@ -523,9 +554,26 @@ class ServeEngine:
         commit tokens — a cold slot's row is parked, its batched-decode
         output discarded, and its generation resumes bit-identically after
         promotion (greedy decode is row-independent)."""
+        reserved = used = 0
+        if self.paged_kv:
+            reserved = self.pool.n_pages - self.pool.n_free
+            used = self._pages_used()
+            self._tick_pages_reserved += reserved
+            self._tick_pages_used += used
+        with _span("serve.step", tick=self._tick, live=len(self.slot_req),
+                   queued=self.scheduler.pending_count,
+                   pages_reserved=reserved, pages_used=used):
+            self._step()
+            now = time.perf_counter()
+            for held in self._await_out:
+                held.t_out = now
+            self._await_out.clear()
+
+    def _step(self) -> None:
         if self.paged_kv and self.tiered:
             self._tier_tick()
-        self._admit()
+        with _span("serve.admit"):
+            self._admit()
         if self.slot_req:
             if self.paged_kv and self.prefix_share:
                 self._cow_tick()
@@ -536,14 +584,15 @@ class ServeEngine:
                 for slot in sorted(self._active):
                     self.pool.assert_resident(self.slot_pages[slot])
             nxt = self.executor.decode(self._last_tokens)
-            for slot in list(self.slot_req):
-                if self.tiered and slot not in self._active:
-                    continue
-                tok = int(nxt[slot])
-                self.slot_generated[slot].append(tok)
-                self.slot_pos[slot] += 1
-                self._last_tokens[slot, 0] = tok
-                self._finish_if_ended(slot)
+            with _span("serve.commit"):
+                for slot in list(self.slot_req):
+                    if self.tiered and slot not in self._active:
+                        continue
+                    tok = int(nxt[slot])
+                    self.slot_generated[slot].append(tok)
+                    self.slot_pos[slot] += 1
+                    self._last_tokens[slot, 0] = tok
+                    self._finish_if_ended(slot)
         self._tick += 1
 
     def evict_slots(self, slots, *, requeue: bool = True) -> int:
@@ -620,12 +669,10 @@ class ServeEngine:
                 "completions")
         out = list(self.done)
         for slot, req in live:
-            e = self.slot_entry.get(slot)
-            out.append(Completion(req.rid, list(self.slot_generated[slot]),
-                                  False, e.arrival if e else 0, self._tick))
+            out.append(self._completion(req, list(self.slot_generated[slot]),
+                                        False, self.slot_entry.get(slot)))
         for e in queued:
-            out.append(Completion(e.req.rid, [], False, e.arrival,
-                                  self._tick))
+            out.append(self._completion(e.req, [], False, e))
         return out
 
     def stats(self) -> dict:
@@ -638,7 +685,8 @@ class ServeEngine:
                "admitted": self.scheduler.admitted,
                "ticks": self._tick, "incomplete": self._incomplete,
                "max_live": self.max_live, "evictions": self.evictions,
-               "offline_slots": len(self._offline)}
+               "offline_slots": len(self._offline),
+               "prefill_shapes": len(self.executor.prefill_shapes)}
         if self.paged_kv:
             out.update(pages_allocated=self.pool.allocs,
                        pages_freed=self.pool.frees,
@@ -646,7 +694,10 @@ class ServeEngine:
                        page_tokens=self.page_tokens,
                        pages_shared=self.pool.shared_maps,
                        cow_copies=self.pool.cow_copies,
-                       cow_debt=self.pool.cow_debt)
+                       cow_debt=self.pool.cow_debt,
+                       pages_used=self._pages_used(),
+                       tick_pages_reserved=self._tick_pages_reserved,
+                       tick_pages_used=self._tick_pages_used)
             if self.tiered:
                 out.update(host_pages=self.pool.host.capacity,
                            host_pages_free=self.pool.host.n_free,
@@ -658,6 +709,25 @@ class ServeEngine:
         return out
 
     # -- internals --------------------------------------------------------------
+    def _completion(self, req: Request, tokens: list, finished: bool,
+                    entry) -> Completion:
+        if entry is None:
+            return Completion(req.rid, tokens, finished, 0, self._tick)
+        return Completion(req.rid, tokens, finished, entry.arrival,
+                          self._tick, entry.t_submit, entry.t_admit,
+                          entry.t_first, entry.t_out)
+
+    def _pages_used(self) -> int:
+        """Pages of the HBM pool that hold tokens: a hot slot at ``pos``
+        holds ``pos - 1`` tokens in its first pages; a page that slots
+        share counts once, and cold slots hold no HBM pages."""
+        pt = self.page_tokens
+        held = [(pages, -(-(self.slot_pos[s] - 1) // pt))
+                for s, pages in self.slot_pages.items()]
+        if not self.prefix_share:
+            return sum(n for _, n in held)
+        return len({p for pages, n in held for p in pages[:n]})
+
     def _finish_if_ended(self, slot: int) -> bool:
         """Complete-and-release ``slot`` iff its latest token terminates the
         request (EOS, token budget, or cache full) — the single termination
@@ -668,9 +738,10 @@ class ServeEngine:
                  len(gen) >= req.max_new_tokens or
                  self.slot_pos[slot] >= self.max_seq - 1)
         if ended:
-            e = self.slot_entry.get(slot)
-            self.done.append(Completion(req.rid, gen, True,
-                                        e.arrival if e else 0, self._tick))
+            c = self._completion(req, gen, True, self.slot_entry.get(slot))
+            if c.t_first and not c.t_out:    # its first token is this tick's
+                self._await_out.append(c)
+            self.done.append(c)
             self._release(slot)
         return ended
 
@@ -710,7 +781,6 @@ class ServeEngine:
         state changed, entry must be requeued) when the pool cannot back it
         fork-safely."""
         req = entry.req
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None]
         if self.paged_kv:
             shared, shared_rw = ([], [])
             if self.prefix_share:
@@ -747,7 +817,13 @@ class ServeEngine:
         else:
             phys_arg = jnp.zeros((0,), jnp.int32)
             ok_arg = jnp.zeros((0,), bool)
-        first = self.executor.prefill(tokens, slot, phys_arg, ok_arg)
+        with _span("serve.prefill", rid=req.rid, prompt_len=len(req.prompt),
+                   slot=slot):
+            entry.t_admit, entry.t_out = time.perf_counter(), 0.0
+            tokens = jnp.asarray(req.prompt, jnp.int32)[None]
+            first = self.executor.prefill(tokens, slot, phys_arg, ok_arg)
+        entry.t_first = time.perf_counter()
+        self._await_out.append(entry)
         self.slot_free[slot] = False
         self.slot_req[slot] = req
         self.slot_generated[slot] = [first]
@@ -958,15 +1034,17 @@ class ServeEngine:
         del self.slot_pos[slot]
         self.slot_entry.pop(slot, None)
         if self.paged_kv and slot in self.slot_pages:
-            # park the row before its pages go back to the free list: idle
-            # rows keep scattering per-step KV, and those writes must never
-            # land on pages a later admission may own
-            self.executor.park(slot)
-            dropped = self.pool.release(self.slot_pages.pop(slot))
-            ro_clear = [p for p in dropped if p in self._ro_pages]
-            if ro_clear:
-                self.executor.set_pages_ro(ro_clear, False)
-                self._ro_pages.difference_update(ro_clear)
+            pages = self.slot_pages.pop(slot)
+            with _span("serve.release", slot=slot, pages=len(pages)):
+                # park the row before its pages go back to the free list:
+                # idle rows keep scattering per-step KV, and those writes
+                # must never land on pages a later admission may own
+                self.executor.park(slot)
+                dropped = self.pool.release(pages)
+                ro_clear = [p for p in dropped if p in self._ro_pages]
+                if ro_clear:
+                    self.executor.set_pages_ro(ro_clear, False)
+                    self._ro_pages.difference_update(ro_clear)
         if self.paged_kv and self.tiered:
             self._active.discard(slot)
             self._hot_since.pop(slot, None)

@@ -48,7 +48,8 @@ POLICIES = ("continuous", "static", "priority", "fair")
 
 @dataclasses.dataclass
 class SchedEntry:
-    """A queued request plus its arrival bookkeeping."""
+    """A queued request plus its arrival bookkeeping and its host times
+    (``time.perf_counter``; 0.0 until reached)."""
 
     req: object               # repro.serve.engine.Request
     arrival: int              # engine tick at submission
@@ -56,6 +57,9 @@ class SchedEntry:
     seq: int                  # monotone submission index (FIFO tiebreak)
     priority: int = 0
     tenant: int = 0
+    t_admit: float = 0.0      # its prefill dispatched
+    t_first: float = 0.0      # its first token read on the host
+    t_out: float = 0.0        # the end of the tick that made that token
 
 
 class Scheduler:
@@ -203,14 +207,7 @@ class Scheduler:
 
     # -- health ----------------------------------------------------------------
     def stats(self) -> dict:
-        return {
-            "policy": self.policy,
-            "pending": len(self._queue),
-            "submitted": self.submitted,
-            "admitted": self.admitted,
-            "tenants": dict(self._tenant_admitted),
-            "outstanding_claims": dict(self._claims),
-        }
+        return {"outstanding_claims": dict(self._claims)}
 
 
 __all__ = ["Scheduler", "SchedEntry", "POLICIES"]
