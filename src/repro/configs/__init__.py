@@ -6,6 +6,7 @@ from repro.configs.base import (
     SHAPES,
     SSMConfig,
     ShapeConfig,
+    YarnConfig,
     cell_is_runnable,
     get_config,
     list_archs,
@@ -13,7 +14,7 @@ from repro.configs.base import (
 )
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig",
+    "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig", "YarnConfig",
     "ShapeConfig", "SHAPES",
     "register", "get_config", "list_archs", "cell_is_runnable",
 ]

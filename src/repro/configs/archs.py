@@ -9,6 +9,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     SSMConfig,
+    YarnConfig,
     register,
 )
 
@@ -144,7 +145,10 @@ def deepseek_v2() -> ModelConfig:
     [arXiv:2405.04434; hf].
 
     60L, d=5120, 128H, expert ff=1536, vocab=102400; layer 0 dense (ff=12288,
-    per the HF config).
+    per the HF config).  Gates are not renormalised (``norm_topk_prob``
+    false).  Not implemented: the published ``group_limited_greedy`` routing
+    (8 groups, top 3) -- the router here is greedy top-6 over all 160 --
+    and ``routed_scaling_factor`` 16 (here 1), nor its YaRN rope.
     """
     return ModelConfig(
         name="deepseek-v2-236b", family="moe",
@@ -156,7 +160,38 @@ def deepseek_v2() -> ModelConfig:
         moe=MoEConfig(num_experts=160, top_k=6, d_ff_expert=1536,
                       n_shared=2, d_ff_shared=2 * 1536,
                       interleave_step=1, interleave_offset=0,
-                      first_dense=1, d_ff_first_dense=12288),
+                      first_dense=1, d_ff_first_dense=12288,
+                      renorm_gates=False),
+    )
+
+
+@register("deepseek-v2-lite")
+def deepseek_v2_lite() -> ModelConfig:
+    """[moe] MLA without query LoRA + 2 shared + 64 routed top-6, YaRN rope
+    [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite].
+
+    27L, d=2048, 16H, kv_lora=512, qk_nope=128, qk_rope=64, v_head=128;
+    layer 0 a dense SwiGLU of 10944, layers 1-26 MoE: 64 experts of 1408,
+    softmax router, greedy top-6, gates not renormalised
+    (``norm_topk_prob`` false, ``routed_scaling_factor`` 1), 2 shared
+    experts of 1408 (one SwiGLU of 2816).  RMSNorm eps 1e-6, untied head,
+    vocab 102400, YaRN (factor 40 over 4096 positions) to 163840.
+    """
+    return ModelConfig(
+        name="deepseek-v2-lite", family="moe",
+        n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
+        d_ff=10944, vocab=102400, norm_eps=1e-6,
+        rope_theta=1e4, max_seq=163840,
+        rope_scaling=YarnConfig(factor=40.0,
+                                original_max_position_embeddings=4096,
+                                beta_fast=32.0, beta_slow=1.0,
+                                mscale=0.707, mscale_all_dim=0.707),
+        mla=MLAConfig(q_lora=0, kv_lora=512, qk_nope=128, qk_rope=64,
+                      v_head=128),
+        moe=MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
+                      n_shared=2, d_ff_shared=2816,
+                      first_dense=1, d_ff_first_dense=10944,
+                      renorm_gates=False),
     )
 
 

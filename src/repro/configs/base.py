@@ -34,6 +34,18 @@ class MoEConfig:
     #: lax.all_to_all), or "auto" (calibrated cost-model pick); the
     #: host-side "interpret" target is invalid inside a mesh.
     ep_backend: str = "rma"
+    #: the contiguous expert ids ``(first, stop)`` whose weights this chip
+    #: holds (``None``: all of them).  The router keeps every expert and
+    #: its top-k over all of them; the layer adds only the held experts'
+    #: part of the routed sum, with no capacity drop (``moe.py``).
+    experts_held: tuple[int, int] | None = None
+
+    @property
+    def n_held(self) -> int:
+        if self.experts_held is None:
+            return self.num_experts
+        first, stop = self.experts_held
+        return stop - first
 
     def capacity(self, tokens: int) -> int:
         c = math.ceil(tokens * self.top_k * self.capacity_factor / self.num_experts)
@@ -42,11 +54,24 @@ class MoEConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
+    #: 0: no query LoRA, the query is projected straight from x (``w_q``)
     q_lora: int = 1536
     kv_lora: int = 512
     qk_nope: int = 128
     qk_rope: int = 64
     v_head: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` with
+    ``type: yarn``); ``models/layers.py`` applies it."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +95,7 @@ class ModelConfig:
     vocab: int
     head_dim: int = 0          # 0 -> d_model // n_heads
     rope_theta: float = 10000.0
+    rope_scaling: YarnConfig | None = None
     qk_norm: bool = False
     attn_bias: bool = False
     norm: str = "rmsnorm"      # rmsnorm | layernorm
@@ -182,7 +208,7 @@ def cell_is_runnable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
 
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig",
+    "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig", "YarnConfig",
     "ShapeConfig", "SHAPES",
     "register", "get_config", "list_archs", "cell_is_runnable",
 ]

@@ -24,7 +24,8 @@ def tiny_config(arch: str, *, dtype: str = "float32") -> ModelConfig:
         kw.update(n_heads=4, n_kv_heads=2 if cfg.n_kv_heads < cfg.n_heads else 4,
                   head_dim=16)
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(q_lora=32, kv_lora=32, qk_nope=16, qk_rope=8, v_head=16)
+        kw["mla"] = MLAConfig(q_lora=32 if cfg.mla.q_lora else 0, kv_lora=32,
+                              qk_nope=16, qk_rope=8, v_head=16)
         kw.update(n_heads=4, n_kv_heads=4, head_dim=16)
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(

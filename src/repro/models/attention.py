@@ -152,6 +152,49 @@ def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
 # ---------------------------------------------------------------------------
 
 
+def _page_routes(cache: dict, cols: Array, n_pages: int, pt: int):
+    """Where a paged cache's new tokens land and what its rows read.
+
+    ``cols`` (B, S) are the new tokens' positions; the pool has ``n_pages``
+    physical pages (the last is the parking page) of ``pt`` tokens.
+    Returns ``(phys, in_page, gather_table)``: each new token's physical
+    page and offset in it, and the page table the attention gather reads
+    through.  A row at ``pos == max_seq`` has no page for the new token;
+    its scatter goes to an out-of-range physical id so it is dropped -- the
+    same silent OOB-write drop the dense layout gives.  The same rules hold
+    for GQA ``{k_pages, v_pages}`` and MLA ``{ckv_pages, kr_pages}`` pools.
+    """
+    table = cache["page_table"]            # (B, pages_per_row) int32
+    rows = jnp.arange(table.shape[0])[:, None]
+    page_idx = cols // pt
+    pages_per_row = table.shape[-1]
+    valid = page_idx < pages_per_row
+    phys = table[rows, jnp.minimum(page_idx, pages_per_row - 1)]
+    phys = jnp.where(valid, phys, n_pages)  # (B, S) page ids
+    if "page_ro" in cache:
+        # COW prefix sharing: a page mapped by >1 sequence is
+        # write-protected — the pool manager forks before any legitimate
+        # write reaches one, so a scatter aimed at it means host and device
+        # state disagree; drop it like an overflow write rather than corrupt
+        # the co-holder.  Only the scatter is rerouted — the attention
+        # gather still reads shared pages through the table.
+        ro = cache["page_ro"][jnp.minimum(phys, n_pages - 1)]
+        phys = jnp.where(ro, n_pages, phys)
+    gather_table = table
+    if "page_hot" in cache:
+        # tiered residency: a non-hot page's bytes live in the host tier
+        # (demoted) or are mid-migration — the engine never decodes such a
+        # slot, so a table entry still aimed at one means residency
+        # bookkeeping and device state disagree.  Drop scatters at it like
+        # overflow writes and reroute the gather to the (all-zero,
+        # always-hot) parking page rather than read a physical page the
+        # pool may have re-issued.
+        hot = cache["page_hot"]
+        phys = jnp.where(hot[jnp.minimum(phys, n_pages - 1)], phys, n_pages)
+        gather_table = jnp.where(hot[table], table, n_pages - 1)
+    return phys, cols % pt, gather_table
+
+
 def gqa_attention(
     params: dict,
     x: Array,
@@ -214,40 +257,8 @@ def gqa_attention(
             # scatter into the row's current physical page; attention
             # gathers the row's pages back into a contiguous logical view.
             kp, vp = cache["k_pages"], cache["v_pages"]
-            table = cache["page_table"]        # (B, pages_per_row) int32
-            pt = kp.shape[1]                   # page_tokens
-            page_idx = cols // pt
-            pages_per_row = table.shape[-1]
-            # a row at pos == max_seq has no page for the new token; route
-            # its scatter to an out-of-range physical id so it is dropped —
-            # the same silent OOB-write drop the dense layout gives
-            valid = page_idx < pages_per_row
-            phys = table[rows, jnp.minimum(page_idx, pages_per_row - 1)]
-            phys = jnp.where(valid, phys, kp.shape[0])  # (B, S) page ids
-            if "page_ro" in cache:
-                # COW prefix sharing: a page mapped by >1 sequence is
-                # write-protected — the pool manager forks before any
-                # legitimate write reaches one, so a scatter aimed at it
-                # means host and device state disagree; drop it like an
-                # overflow write rather than corrupt the co-holder.  Only
-                # the scatter is rerouted — the attention gather below
-                # still reads shared pages through the table.
-                ro = cache["page_ro"][jnp.minimum(phys, kp.shape[0] - 1)]
-                phys = jnp.where(ro, kp.shape[0], phys)
-            gather_table = table
-            if "page_hot" in cache:
-                # tiered residency: a non-hot page's bytes live in the host
-                # tier (demoted) or are mid-migration — the engine never
-                # decodes such a slot, so a table entry still aimed at one
-                # means residency bookkeeping and device state disagree.
-                # Drop scatters at it like overflow writes and reroute the
-                # gather to the (all-zero, always-hot) parking page rather
-                # than read a physical page the pool may have re-issued.
-                hot = cache["page_hot"]
-                phys = jnp.where(hot[jnp.minimum(phys, kp.shape[0] - 1)],
-                                 phys, kp.shape[0])
-                gather_table = jnp.where(hot[table], table, kp.shape[0] - 1)
-            in_page = cols % pt
+            phys, in_page, gather_table = _page_routes(cache, cols, kp.shape[0],
+                                                       kp.shape[1])
             ckp = kp.at[phys, in_page].set(k.astype(kp.dtype))
             cvp = vp.at[phys, in_page].set(v.astype(vp.dtype))
             new_cache = dict(cache, k_pages=ckp, v_pages=cvp, pos=pos + S)
@@ -365,31 +376,151 @@ def init_mla(key, cfg) -> dict:
     d, H = cfg.d_model, cfg.n_heads
     ks = jax.random.split(key, 7)
     pd = cfg.param_dtype
-    return {
-        "w_dq": layers.trunc_normal(ks[0], (d, m.q_lora), 1.0, pd),
-        "q_norm": layers.init_rmsnorm(m.q_lora, pd),
-        "w_uq": layers.trunc_normal(ks[1], (m.q_lora, H, m.qk_nope + m.qk_rope), 1.0, pd),
-        "w_dkv": layers.trunc_normal(ks[2], (d, m.kv_lora), 1.0, pd),
-        "kv_norm": layers.init_rmsnorm(m.kv_lora, pd),
-        "w_kr": layers.trunc_normal(ks[3], (d, m.qk_rope), 1.0, pd),
-        "w_uk": layers.trunc_normal(ks[4], (m.kv_lora, H, m.qk_nope), 1.0, pd),
-        "w_uv": layers.trunc_normal(ks[5], (m.kv_lora, H, m.v_head), 1.0, pd),
-        "wo": layers.trunc_normal(ks[6], (H, m.v_head, d), 1.0, pd),
-    }
+    qk = m.qk_nope + m.qk_rope
+    if m.q_lora:
+        p = {"w_dq": layers.trunc_normal(ks[0], (d, m.q_lora), 1.0, pd),
+             "q_norm": layers.init_rmsnorm(m.q_lora, pd),
+             "w_uq": layers.trunc_normal(ks[1], (m.q_lora, H, qk), 1.0, pd)}
+    else:
+        p = {"w_q": layers.trunc_normal(ks[1], (d, H, qk), 1.0, pd)}
+    return dict(
+        p,
+        w_dkv=layers.trunc_normal(ks[2], (d, m.kv_lora), 1.0, pd),
+        kv_norm=layers.init_rmsnorm(m.kv_lora, pd),
+        w_kr=layers.trunc_normal(ks[3], (d, m.qk_rope), 1.0, pd),
+        w_uk=layers.trunc_normal(ks[4], (m.kv_lora, H, m.qk_nope), 1.0, pd),
+        w_uv=layers.trunc_normal(ks[5], (m.kv_lora, H, m.v_head), 1.0, pd),
+        wo=layers.trunc_normal(ks[6], (H, m.v_head, d), 1.0, pd),
+    )
 
 
 def mla_spec(cfg) -> dict:
-    return {
-        "w_dq": ("embed", "q_lora"),
-        "q_norm": layers.rmsnorm_spec(),
-        "w_uq": ("q_lora", "heads", None),
-        "w_dkv": ("embed", "kv_lora"),
-        "kv_norm": layers.rmsnorm_spec(),
-        "w_kr": ("embed", None),
-        "w_uk": ("kv_lora", "heads", None),
-        "w_uv": ("kv_lora", "heads", None),
-        "wo": ("heads", None, "embed"),
-    }
+    if cfg.mla.q_lora:
+        p = {"w_dq": ("embed", "q_lora"), "q_norm": layers.rmsnorm_spec(),
+             "w_uq": ("q_lora", "heads", None)}
+    else:
+        p = {"w_q": ("embed", "heads", None)}
+    return dict(
+        p,
+        w_dkv=("embed", "kv_lora"),
+        kv_norm=layers.rmsnorm_spec(),
+        w_kr=("embed", None),
+        w_uk=("kv_lora", "heads", None),
+        w_uv=("kv_lora", "heads", None),
+        wo=("heads", None, "embed"),
+    )
+
+
+def mla_softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times ``mscale(factor,
+    mscale_all_dim)²`` under YaRN (DeepSeek-V2's softmax temperature)."""
+    m, yarn = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope + m.qk_rope) ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= layers.yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_blockwise(q, c_all, kr_all, params, *, qpos, n_keys, scale,
+                   block_kv: int):
+    """Causal MLA over the first ``n_keys`` positions of the latent
+    ``c_all`` (B, S_k, r) and ``kr_all`` (B, S_k, rope), in the expanded
+    form: K = [c·W_uk, k_rope] and V = c·W_uv are built from the latent
+    once (a few tens of MB a layer), then attended block by block with an
+    online softmax, so no ``(H, S, S_k)`` score tensor ever exists.  Queries
+    go in blocks too, and each query block visits only the key blocks at or
+    before its last position (dynamic trip counts): about half the pairs of
+    a prompt, where the score blocks' f32 passes set the time.  ``q`` (B, S,
+    H, nope+rope); ``qpos`` (B, S).  Returns (B, S, H, v_head) in ``q``'s
+    dtype."""
+    B, S, H, D = q.shape
+    dt = q.dtype
+    S_k = c_all.shape[1]
+    bk = min(block_kv, S_k)
+    bq = min(block_kv, S)
+    c_all = jnp.pad(c_all, ((0, 0), (0, (-S_k) % bk), (0, 0))).astype(dt)
+    kr_all = jnp.pad(kr_all, ((0, 0), (0, (-S_k) % bk), (0, 0))).astype(dt)
+    k_nope = jnp.einsum("btr,rhk->bthk", c_all, params["w_uk"].astype(dt))
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        kr_all[:, :, None, :], k_nope.shape[:3] + kr_all.shape[-1:])], axis=-1)
+    v = jnp.einsum("btr,rhv->bthv", c_all, params["w_uv"].astype(dt))
+    v_head = v.shape[-1]
+    n_kb = (n_keys + bk - 1) // bk
+    pad_q = (-S) % bq
+    # padded queries sit at position -1: they see no key and are dropped
+    qs = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
+    ps = jnp.pad(qpos, ((0, 0), (0, pad_q)), constant_values=-1)
+    nq = qs.shape[1] // bq
+    qs = jnp.moveaxis(qs.reshape(B, nq, bq, H, D), 1, 0)
+    ps = jnp.moveaxis(ps.reshape(B, nq, bq), 1, 0)
+
+    def q_block(args):
+        qb, pb = args
+
+        def body(i, carry):
+            m, l, acc = carry
+            kb = lax.dynamic_slice_in_dim(k, i * bk, bk, axis=1)
+            vb = lax.dynamic_slice_in_dim(v, i * bk, bk, axis=1)
+            sc = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
+                            preferred_element_type=jnp.float32) * scale
+            kpos = i * bk + jnp.arange(bk)
+            sc = jnp.where(pb[:, None, :, None] >= kpos, sc, NEG_INF)
+            m_new = jnp.maximum(m, sc.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new[..., None])
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bhqk,bkhd->bhqd", p.astype(dt), vb,
+                preferred_element_type=jnp.float32)
+            return m_new, l * alpha + p.sum(axis=-1), acc
+
+        init = (jnp.full((B, H, bq), NEG_INF, jnp.float32),
+                jnp.zeros((B, H, bq), jnp.float32),
+                jnp.zeros((B, H, bq, v_head), jnp.float32))
+        if isinstance(n_kb, int):
+            # no cache (training): a static count keeps it differentiable
+            trips = n_kb
+        else:
+            trips = jnp.minimum(jnp.max(pb) // bk + 1, n_kb)  # blocks it sees
+        _, l, acc = lax.fori_loop(0, trips, body, init)
+        return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(dt)
+
+    out = lax.map(q_block, (qs, ps))              # (nq, B, H, bq, v_head)
+    out = jnp.moveaxis(out, 0, 2).reshape(B, H, nq * bq, v_head)[:, :, :S]
+    return jnp.moveaxis(out, 1, 2)                # (B, S, H, v_head)
+
+
+def _mla_absorbed(q_nope, q_rope, c_all, kr_all, params, *, qpos, scale):
+    """MLA in the absorbed form, for a decode step: the query goes through
+    ``W_uk`` into the latent space and scores against the cached ``c_kv``
+    directly (plus ``q_rope·k_rope``); the weights average the latent and
+    ``W_uv`` expands the result once -- the cache is read as stored, never
+    expanded.  Operands in the compute dtype, f32 accumulation, f32
+    softmax (as the GQA decode)."""
+    dt = q_nope.dtype
+    S_k = c_all.shape[1]
+    c_all, kr_all = c_all.astype(dt), kr_all.astype(dt)
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, params["w_uk"].astype(dt))
+    scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_all,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshk,btk->bhst", q_rope, kr_all,
+                           preferred_element_type=jnp.float32)) * scale
+    mask = qpos[:, None, :, None] >= jnp.arange(S_k)[None, None, None, :]
+    w = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1).astype(dt)
+    ctx = jnp.einsum("bhst,btr->bshr", w, c_all)
+    return jnp.einsum("bshr,rhv->bshv", ctx, params["w_uv"].astype(dt))
+
+
+def _mla_paged_write(cache, c_kv, k_rope, cols):
+    """Scatter the new latents into each row's pages (:func:`_page_routes`)
+    and gather every row's logical latent view through its page table."""
+    cp, kp = cache["ckv_pages"], cache["kr_pages"]
+    phys, in_page, gather_table = _page_routes(cache, cols, cp.shape[0], cp.shape[1])
+    cp = cp.at[phys, in_page].set(c_kv.astype(cp.dtype))
+    kp = kp.at[phys, in_page].set(k_rope.astype(kp.dtype))
+    B = cols.shape[0]
+    c_all = cp[gather_table].reshape(B, -1, cp.shape[-1])
+    kr_all = kp[gather_table].reshape(B, -1, kp.shape[-1])
+    return dict(cache, ckv_pages=cp, kr_pages=kp), c_all, kr_all
 
 
 def mla_attention(
@@ -403,64 +534,62 @@ def mla_attention(
 ) -> tuple[Array, dict | None]:
     """DeepSeek-V2 multi-head latent attention.
 
-    The KV cache stores only (c_kv: kv_lora, k_rope: qk_rope) per token —
-    the compression that makes 128-head attention servable.
+    The KV cache stores only (c_kv: kv_lora, k_rope: qk_rope) per token --
+    the compression that makes many-head attention servable -- densely
+    (``{c_kv, k_rope, pos}``) or in pages (``{ckv_pages, kr_pages,
+    page_table, ...}``, :func:`repro.serve.disagg.paginate_cache`).  A
+    one-token step against a cache (decode) attends in the absorbed form
+    (:func:`_mla_absorbed`); a prompt, with or without a cache, in the
+    expanded form over key blocks (:func:`_mla_blockwise`).
     """
     m = cfg.mla
     B, S, d = x.shape
-    H = cfg.n_heads
     dt = x.dtype
+    yarn = cfg.rope_scaling
 
-    cq = layers.rms_norm(jnp.einsum("bsd,dr->bsr", x, params["w_dq"].astype(dt)),
-                         params["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"].astype(dt))
+    if "w_q" in params:
+        q = jnp.einsum("bsd,dhk->bshk", x, params["w_q"].astype(dt))
+    else:
+        cq = layers.rms_norm(jnp.einsum("bsd,dr->bsr", x, params["w_dq"].astype(dt)),
+                             params["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"].astype(dt))
     q_nope, q_rope = q[..., : m.qk_nope], q[..., m.qk_nope:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta, yarn)
 
     c_kv = layers.rms_norm(jnp.einsum("bsd,dr->bsr", x, params["w_dkv"].astype(dt)),
                            params["kv_norm"], cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, params["w_kr"].astype(dt))
-    k_rope = layers.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = layers.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                               yarn)[:, :, 0]
 
     if cache is not None:
         pos = cache["pos"]  # (B,)
-        rows = jnp.arange(B)[:, None]
         cols = pos[:, None] + jnp.arange(S)[None, :]
-        ckv = cache["c_kv"].at[rows, cols].set(c_kv.astype(cache["c_kv"].dtype))
-        ckr = cache["k_rope"].at[rows, cols].set(
-            k_rope.astype(cache["k_rope"].dtype))
-        new_cache = dict(cache, c_kv=ckv, k_rope=ckr, pos=pos + S)
-        c_all, kr_all = ckv.astype(dt), ckr.astype(dt)
-        S_k = c_all.shape[1]
-        q_offset = pos[:, None]  # (B, 1)
+        if "ckv_pages" in cache:
+            new_cache, c_all, kr_all = _mla_paged_write(cache, c_kv, k_rope, cols)
+        else:
+            rows = jnp.arange(B)[:, None]
+            c_all = cache["c_kv"].at[rows, cols].set(c_kv.astype(cache["c_kv"].dtype))
+            kr_all = cache["k_rope"].at[rows, cols].set(
+                k_rope.astype(cache["k_rope"].dtype))
+            new_cache = dict(cache, c_kv=c_all, k_rope=kr_all)
+        new_cache["pos"] = pos + S
+        qpos = cols
+        n_keys = jnp.max(pos) + S
     else:
         new_cache = None
         c_all, kr_all = c_kv, k_rope
-        S_k = S
-        q_offset = None
+        qpos = jnp.broadcast_to(jnp.arange(S), (B, S))
+        n_keys = S
 
-    # absorbed-weight form: score = q_nope·(W_uk c) + q_rope·k_rope.
-    # Project q through W_uk once (H·nope·lora flops) so the cache stays
-    # compressed — no per-token K expansion (the serving-time win).
-    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, params["w_uk"].astype(dt))
-    scale = (m.qk_nope + m.qk_rope) ** -0.5
-    s_lat = jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32),
-                       c_all.astype(jnp.float32))
-    s_rope = jnp.einsum("bshk,btk->bhst", q_rope.astype(jnp.float32),
-                        kr_all.astype(jnp.float32))
-    scores = (s_lat + s_rope) * scale
-    kpos = jnp.arange(S_k)
-    if q_offset is None:
-        qpos = jnp.arange(S)
-        mask = (qpos[:, None] >= kpos[None, :])[None, None]       # (1,1,S,K)
+    scale = mla_softmax_scale(cfg)
+    if cache is not None and S == 1:
+        out = _mla_absorbed(q_nope, q_rope, c_all, kr_all, params,
+                            qpos=qpos, scale=scale)
     else:
-        qpos = q_offset + jnp.arange(S)[None, :]                   # (B, S)
-        mask = qpos[:, None, :, None] >= kpos[None, None, None, :]  # (B,1,S,K)
-    scores = jnp.where(mask, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    # attend in the latent space, then expand once: out_h = (w·c) @ W_uv
-    ctx = jnp.einsum("bhst,btr->bshr", w.astype(dt), c_all)
-    out = jnp.einsum("bshr,rhv->bshv", ctx, params["w_uv"].astype(dt))
+        out = _mla_blockwise(jnp.concatenate([q_nope, q_rope], axis=-1),
+                             c_all, kr_all, params, qpos=qpos, n_keys=n_keys,
+                             scale=scale, block_kv=block_kv)
     out = logical_constraint(out, "batch", "seq", "heads", None)
     proj = jnp.einsum("bshv,hvd->bsd", out, params["wo"].astype(dt))
     return proj, new_cache
@@ -486,6 +615,7 @@ def mla_cache_spec(cfg) -> dict:
 __all__ = [
     "init_gqa", "gqa_spec", "gqa_attention", "init_gqa_cache", "gqa_cache_spec",
     "init_paged_gqa_cache",
-    "init_mla", "mla_spec", "mla_attention", "init_mla_cache", "mla_cache_spec",
+    "init_mla", "mla_spec", "mla_attention", "mla_softmax_scale",
+    "init_mla_cache", "mla_cache_spec",
     "full_attention", "blockwise_attention",
 ]

@@ -12,6 +12,8 @@ Conventions used across the model zoo:
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,23 +171,60 @@ def gelu_mlp(x: Array, params: dict) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float) -> Array:
-    """Inverse frequencies for RoPE (fp32)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_ramp_range(head_dim: int, theta: float, yarn) -> tuple[int, int]:
+    """The dim pairs over which YaRN blends the interpolated frequencies
+    into the original ones: ``[floor(fcd(beta_fast)), ceil(fcd(beta_slow))]``
+    with ``fcd(r) = d·ln(L0 / (2π·r)) / (2·ln θ)``, clipped to the dims."""
+    L0 = yarn.original_max_position_embeddings
+
+    def fcd(rotations):
+        return (head_dim * math.log(L0 / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(fcd(yarn.beta_fast)), 0)
+    hi = min(math.ceil(fcd(yarn.beta_slow)), head_dim - 1)
+    return lo, hi
+
+
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> Array:
+    """Inverse frequencies for RoPE (fp32).  With ``yarn`` (a
+    :class:`repro.configs.base.YarnConfig`), the DeepSeek-V2 YaRN rule:
+    pairs below the ramp keep their frequency, pairs above it are divided
+    by ``factor``, and the ramp blends the two linearly."""
     exponents = np.arange(0, head_dim, 2, dtype=np.float32) / head_dim
-    return jnp.asarray(1.0 / (theta ** exponents))
+    inv = 1.0 / (theta ** exponents)
+    if yarn is not None:
+        lo, hi = yarn_ramp_range(head_dim, theta, yarn)
+        ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - lo)
+                       / max(hi - lo, 1e-3), 0.0, 1.0)
+        keep = 1.0 - ramp
+        inv = inv * keep + inv / yarn.factor * (1.0 - keep)
+    return jnp.asarray(inv, jnp.float32)
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
+def apply_rope(x: Array, positions: Array, theta: float, yarn=None) -> Array:
     """Rotate ``x`` (..., seq, heads, head_dim) by position-dependent angles.
 
     ``positions``: (..., seq) int32 absolute positions (decode passes the
     cache offset).  Uses the half-split convention (LLaMA/NeoX style).
+    ``yarn`` scales the frequencies (:func:`rope_frequencies`) and the
+    cos/sin by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``.
     """
     *_, seq, heads, hd = x.shape
-    inv = rope_frequencies(hd, theta)  # (hd/2,)
+    inv = rope_frequencies(hd, theta, yarn)  # (hd/2,)
     angles = positions.astype(jnp.float32)[..., :, None] * inv[None, :]  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]  # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., :, None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -218,6 +257,6 @@ __all__ = [
     "init_lm_head", "lm_head_spec", "lm_head",
     "init_swiglu", "swiglu_spec", "swiglu",
     "init_gelu_mlp", "gelu_mlp_spec", "gelu_mlp",
-    "rope_frequencies", "apply_rope",
+    "yarn_mscale", "yarn_ramp_range", "rope_frequencies", "apply_rope",
     "init_learned_pos", "learned_pos_spec", "add_learned_pos",
 ]

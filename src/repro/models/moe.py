@@ -40,14 +40,16 @@ Array = jax.Array
 
 
 def init_moe(key, cfg) -> dict:
+    """Router over every expert; ``wi``/``wo`` of the held experts only
+    (``MoEConfig.experts_held``; all of them by default)."""
     mo = cfg.moe
     d = cfg.d_model
     ks = jax.random.split(key, 4)
     p = {
         "router": layers.trunc_normal(ks[0], (d, mo.num_experts), 1.0, jnp.float32),
-        "wi": layers.trunc_normal(ks[1], (mo.num_experts, d, 2 * mo.d_ff_expert), 1.0,
+        "wi": layers.trunc_normal(ks[1], (mo.n_held, d, 2 * mo.d_ff_expert), 1.0,
                                   cfg.param_dtype),
-        "wo": layers.trunc_normal(ks[2], (mo.num_experts, mo.d_ff_expert, d), 1.0,
+        "wo": layers.trunc_normal(ks[2], (mo.n_held, mo.d_ff_expert, d), 1.0,
                                   cfg.param_dtype),
     }
     if mo.n_shared:
@@ -80,6 +82,9 @@ def moe_apply(params: dict, x: Array, cfg, *, return_aux: bool = False,
         raise ValueError(f"unknown ep_mode {mode!r}; expected 'gspmd' or 'rma'")
     if mode == "rma":
         return _moe_apply_rma(params, x, cfg)
+    if cfg.moe.experts_held is not None:
+        out, aux, _ = moe_apply_held(params, x, cfg)
+        return out, aux
     mo = cfg.moe
     B, S, d = x.shape
     dt = x.dtype
@@ -139,6 +144,98 @@ def moe_apply(params: dict, x: Array, cfg, *, return_aux: bool = False,
     if return_aux:
         return out, aux
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# an expert share: the experts this chip holds, dropless
+# ---------------------------------------------------------------------------
+
+#: rows of one tile of the grouped expert matmul (the MXU's 128)
+HELD_TILE = 128
+
+
+def _grouped_swiglu(xs: Array, counts: Array, starts: Array, wi: Array,
+                    wo: Array, tm: int) -> Array:
+    """SwiGLU experts over rows grouped by expert: rows ``starts[e] …
+    starts[e] + counts[e] − 1`` of ``xs`` (R, d) go through expert ``e``.
+    Each expert runs ``ceil(counts[e] / tm)`` tiles of ``tm`` rows (dynamic
+    trip counts), so an expert no row chose is never read and no row is
+    dropped.  Returns (R, d) in ``xs``' dtype; rows past every group are
+    zero."""
+    R, d = xs.shape
+    dt = xs.dtype
+    xs = jnp.concatenate([xs, jnp.zeros((tm, d), dt)])     # tiles never clamp
+
+    def expert(e, out):
+        def tile(t, out):
+            r0 = starts[e] + t * tm
+            h = jnp.einsum("td,df->tf", lax.dynamic_slice_in_dim(xs, r0, tm),
+                           wi[e].astype(dt))
+            gate, up = jnp.split(h, 2, axis=-1)
+            h = jax.nn.silu(gate.astype(jnp.float32)).astype(dt) * up
+            y = jnp.einsum("tf,fd->td", h, wo[e].astype(dt))
+            valid = (t * tm + jnp.arange(tm) < counts[e])[:, None]
+            old = lax.dynamic_slice_in_dim(out, r0, tm)
+            return lax.dynamic_update_slice_in_dim(
+                out, jnp.where(valid, y, old), r0, axis=0)
+
+        return lax.fori_loop(0, (counts[e] + tm - 1) // tm, tile, out)
+
+    out = lax.fori_loop(0, wi.shape[0], expert, jnp.zeros((R + tm, d), dt))
+    return out[:R]
+
+
+def moe_apply_held(params: dict, x: Array, cfg):
+    """The MoE layer of a chip that holds the experts
+    ``cfg.moe.experts_held = (first, stop)`` of every layer (expert
+    parallelism's share, run without its exchange).
+
+    The router keeps all ``num_experts`` outputs and its top-k over all of
+    them; the layer adds the held experts' part of the routed sum
+    (gate-weighted, gates as the router gives them) and the shared experts.
+    Held assignments are sorted by expert and run through a grouped matmul
+    (:func:`_grouped_swiglu`) with **no capacity**: nothing is dropped,
+    however skewed the routing.  Serving only: the dynamic trip counts are
+    not reverse-differentiable.
+
+    Returns ``(out, aux, counts)``: ``counts`` (2,) int32 is the
+    assignments routed to held experts and the held experts with at least
+    one of them."""
+    mo = cfg.moe
+    B, S, d = x.shape
+    dt = x.dtype
+    T = B * S
+    E, k = mo.num_experts, mo.top_k
+    first, stop = mo.experts_held
+    n = stop - first
+    xt = x.reshape(T, d)
+
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, eidx = lax.top_k(probs, k)  # (T, k)
+    if mo.renorm_gates:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    density = jnp.zeros((E,), jnp.float32).at[eidx.reshape(-1)].add(1.0) / (T * k)
+    aux = E * jnp.sum(density * probs.mean(axis=0))
+
+    local = eidx.reshape(-1) - first
+    flat = jnp.where((local >= 0) & (local < n), local, n)   # n: not held
+    order = jnp.argsort(flat, stable=True)
+    sorted_e = flat[order]
+    tok_of = order // k
+    counts = jnp.zeros((n + 1,), jnp.int32).at[flat].add(1)[:n]
+    starts = jnp.cumsum(counts) - counts
+    tm = min(HELD_TILE, -(-T * k // 8) * 8)
+    ys = _grouped_swiglu(xt[tok_of], counts, starts, params["wi"],
+                         params["wo"], tm)
+    w = jnp.where(sorted_e < n, gates.reshape(-1)[order], 0.0)
+    out = jnp.zeros((T, d), jnp.float32).at[tok_of].add(
+        ys.astype(jnp.float32) * w[:, None])
+    if mo.n_shared:
+        out = out + layers.swiglu(xt, params["shared"]).astype(jnp.float32)
+    stats = jnp.stack([counts.sum(), (counts > 0).sum()]).astype(jnp.int32)
+    return out.astype(dt).reshape(B, S, d), aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +451,8 @@ def _moe_apply_rma(params: dict, x: Array, cfg):
 
 
 def moe_ref(params: dict, x: Array, cfg) -> Array:
-    """Oracle: dense per-token loop over selected experts (no capacity drops).
+    """Oracle: dense per-token loop over selected experts (no capacity drops;
+    only the held experts' part where ``experts_held`` says so).
 
     Used by property tests: when capacity is ample, ``moe_apply`` must match.
     """
@@ -367,8 +465,9 @@ def moe_ref(params: dict, x: Array, cfg) -> Array:
     if mo.renorm_gates:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     out = jnp.zeros_like(xt, dtype=jnp.float32)
-    for e in range(mo.num_experts):
-        wi, wo = params["wi"][e], params["wo"][e]
+    first, stop = mo.experts_held or (0, mo.num_experts)
+    for e in range(first, stop):
+        wi, wo = params["wi"][e - first], params["wo"][e - first]
         h = xt @ wi.astype(xt.dtype)
         g, u = jnp.split(h, 2, axis=-1)
         y = (jax.nn.silu(g.astype(jnp.float32)).astype(xt.dtype) * u) @ wo.astype(xt.dtype)
@@ -379,4 +478,4 @@ def moe_ref(params: dict, x: Array, cfg) -> Array:
     return out.reshape(B, S, d).astype(x.dtype)
 
 
-__all__ = ["init_moe", "moe_spec", "moe_apply", "moe_ref"]
+__all__ = ["init_moe", "moe_spec", "moe_apply", "moe_apply_held", "moe_ref"]

@@ -150,9 +150,39 @@ def block_spec(spec: LayerSpec, cfg) -> dict:
     return p
 
 
+def _counted(spec: LayerSpec, cfg) -> bool:
+    """Whether the block's expert layer holds a share of the experts (and
+    so counts what it routed to them, ``moe_apply_held``)."""
+    return spec.ffn == "moe" and cfg.moe.experts_held is not None
+
+
+def moe_counts(cache):
+    """The sum over a cache tree's ``moe_counts`` leaves -- (assignments
+    routed to held experts, held experts hit), each summed over the expert
+    layers of the call that made the cache -- or ``None`` where it has
+    none."""
+    leaves = []
+
+    def walk(t):
+        if isinstance(t, dict):
+            for key, val in t.items():
+                if key == "moe_counts":
+                    leaves.append(val.reshape(-1, 2).sum(0))
+                else:
+                    walk(val)
+        elif isinstance(t, list):
+            for val in t:
+                walk(val)
+
+    walk(cache)
+    return sum(leaves[1:], leaves[0]) if leaves else None
+
+
 def init_block_cache(spec: LayerSpec, cfg, batch: int, max_seq: int, dtype,
                      enc_len: int = 0) -> dict:
     c: dict = {}
+    if _counted(spec, cfg):
+        c["moe_counts"] = jnp.zeros((2,), jnp.int32)
     if spec.mixer == "gqa":
         c["attn"] = attention.init_gqa_cache(cfg, batch, max_seq, dtype)
     elif spec.mixer == "mla":
@@ -169,6 +199,8 @@ def init_block_cache(spec: LayerSpec, cfg, batch: int, max_seq: int, dtype,
 
 def block_cache_spec(spec: LayerSpec, cfg) -> dict:
     c: dict = {}
+    if _counted(spec, cfg):
+        c["moe_counts"] = (None,)
     if spec.mixer == "gqa":
         c["attn"] = attention.gqa_cache_spec(cfg)
     elif spec.mixer == "mla":
@@ -229,6 +261,10 @@ def apply_block(
         if spec.ffn == "dense":
             out = (layers.gelu_mlp(h, params["mlp"]) if cfg.act == "gelu"
                    else layers.swiglu(h, params["mlp"]))
+        elif _counted(spec, cfg):
+            out, aux, counts = moe_lib.moe_apply_held(params["moe"], h, cfg)
+            if cache is not None:
+                new_cache["moe_counts"] = counts
         else:
             out, aux = moe_lib.moe_apply(params["moe"], h, cfg)
         x = x + out
@@ -379,5 +415,5 @@ __all__ = [
     "init_block", "block_spec", "apply_block",
     "init_block_cache", "block_cache_spec",
     "init_stack", "stack_spec", "apply_stack",
-    "init_stack_cache", "stack_cache_spec",
+    "init_stack_cache", "stack_cache_spec", "moe_counts",
 ]

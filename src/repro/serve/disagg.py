@@ -219,25 +219,59 @@ class PageAllocator:
         return len(self._free)
 
 
-def _is_gqa_cache(d) -> bool:
-    return isinstance(d, dict) and set(d) == {"k", "v", "pos"}
+#: each dense cache kind's leaves and the page pools ``paginate_cache``
+#: turns them into: GQA keys and values, and MLA's latent pair
+PAGED_LEAVES = {
+    frozenset({"k", "v", "pos"}): {"k": "k_pages", "v": "v_pages"},
+    frozenset({"c_kv", "k_rope", "pos"}): {"c_kv": "ckv_pages",
+                                           "k_rope": "kr_pages"},
+}
+
+
+def is_paged(d) -> bool:
+    """Whether ``d`` is a paged self-attention cache (either kind)."""
+    return isinstance(d, dict) and "page_table" in d
+
+
+def page_pools(d) -> dict:
+    """``{dense leaf: page pool}`` of a paged cache dict, in pool order."""
+    for leaves in PAGED_LEAVES.values():
+        if all(pool in d for pool in leaves.values()):
+            return leaves
+    raise ValueError(f"no page pools in a cache dict with keys {sorted(d)}")
+
+
+def page_axis(d) -> int:
+    """The axis of a paged dict's pools that indexes pages: 0, or 1 under
+    the leading (layers) dim of a scanned stack."""
+    return d["page_table"].ndim - 2
+
+
+def parking_page(d) -> int:
+    """The id of a paged dict's parking page (the last page)."""
+    return d["page_ro"].shape[-1] - 1
 
 
 def paginate_cache(cache, page_tokens: int):
-    """Convert every dense GQA KV leaf ``{k, v, pos}`` of a stack cache into
-    the pooled page layout ``{k_pages, v_pages, page_table, pos}``.
+    """Convert every dense self-attention leaf group of a stack cache -- GQA
+    ``{k, v, pos}`` or MLA's latent ``{c_kv, k_rope, pos}`` -- into the
+    pooled page layout: ``{k_pages, v_pages, ...}`` or ``{ckv_pages,
+    kr_pages, ...}`` beside one ``page_table``, ``page_ro``, ``page_hot``
+    and ``pos``.
 
-    Dense ``k``/``v`` leaves of shape ``(…, B, S, KV, hd)`` become physical
-    pools of ``B·S/pt`` allocatable pages **plus one parking page**; every
+    Dense leaves of shape ``(…, B, S, *feature)`` become physical pools
+    of ``B·S/pt`` allocatable pages **plus one parking page**; every
     page-table entry starts pointing at the parking page, and the engine's
     :class:`PageAllocator` (which hands out ids ``0 … B·S/pt − 1``) wires
     rows to real pages at slot admission.  The parking page matters: idle
     and released decode rows still scatter their (discarded) per-step KV
     through the table, and parking those writes on a page no allocation can
     ever own is what keeps them from corrupting a live slot's pages.
-    Leaves that are not self-attention KV (cross-attention, MLA, SSM state,
-    the step counter) pass through unchanged, so hybrid stacks page only
-    what pages.
+    Leaves that are not self-attention KV (cross-attention, SSM state, the
+    step counter, MoE counters) pass through unchanged, so hybrid stacks
+    page only what pages.  Both kinds share the table and the two bit
+    leaves, so the engine's allocator, release, COW fork and tier
+    migration are one code path for both.
 
     The ``page_ro`` leaf is the pool's per-page write protection: the
     engine sets it for pages mapped by more than one sequence (COW prefix
@@ -252,27 +286,33 @@ def paginate_cache(cache, page_tokens: int):
     ``page_ro``, so a residency-bookkeeping bug reads zeros instead of a
     reclaimed page's bytes.  Everything starts hot (an untier'd engine
     never clears it), and the parking page is always hot."""
-    if _is_gqa_cache(cache):
-        k = cache["k"]
-        *lead, b, s, kv, hd = k.shape
+    leaves = (PAGED_LEAVES.get(frozenset(cache)) if isinstance(cache, dict)
+              else None)
+    if leaves is not None:
+        first = cache[next(iter(leaves))]
+        lead = cache["pos"].ndim - 1       # a scanned stack's layers dim
+        b, s = first.shape[lead], first.shape[lead + 1]
         if s % page_tokens:
             raise ValueError(f"max_seq={s} not divisible by "
                              f"page_tokens={page_tokens}")
         pages_per_row = s // page_tokens
         n_alloc = b * pages_per_row        # the allocator's page ids
+        lead_shape = first.shape[:lead]
+
         def repage(x):
-            pool = x.reshape(*lead, n_alloc, page_tokens, kv, hd)
-            park = jnp.zeros((*lead, 1, page_tokens, kv, hd), pool.dtype)
-            return jnp.concatenate([pool, park], axis=len(lead))
-        return {
-            "k_pages": repage(k),
-            "v_pages": repage(cache["v"]),
-            "page_table": jnp.full((*lead, b, pages_per_row), n_alloc,
-                                   jnp.int32),
-            "page_ro": jnp.zeros((*lead, n_alloc + 1), bool),
-            "page_hot": jnp.ones((*lead, n_alloc + 1), bool),
-            "pos": cache["pos"],
-        }
+            feat = x.shape[lead + 2:]
+            pool = x.reshape(*lead_shape, n_alloc, page_tokens, *feat)
+            park = jnp.zeros((*lead_shape, 1, page_tokens, *feat), pool.dtype)
+            return jnp.concatenate([pool, park], axis=lead)
+
+        out = {pool: repage(cache[dense]) for dense, pool in leaves.items()}
+        out.update(
+            page_table=jnp.full((*lead_shape, b, pages_per_row), n_alloc,
+                                jnp.int32),
+            page_ro=jnp.zeros((*lead_shape, n_alloc + 1), bool),
+            page_hot=jnp.ones((*lead_shape, n_alloc + 1), bool),
+            pos=cache["pos"])
+        return out
     if isinstance(cache, dict):
         return {key: paginate_cache(val, page_tokens) for key, val in cache.items()}
     if isinstance(cache, list):
@@ -286,9 +326,9 @@ def park_slot(cache, slot: int):
     land on the parking page and its old (now freed, maybe re-allocated)
     pages are never touched again."""
     if isinstance(cache, dict):
-        if "k_pages" in cache:
+        if is_paged(cache):
             table, pos = cache["page_table"], cache["pos"]
-            park = cache["k_pages"].shape[-4] - 1   # the extra page
+            park = parking_page(cache)
             if table.ndim == 2:
                 table = table.at[slot].set(park)
                 pos = pos.at[slot].set(0)
@@ -429,6 +469,11 @@ __all__ = [
     "read_doorbell",
     "pool_stats",
     "PageAllocator",
+    "PAGED_LEAVES",
+    "is_paged",
+    "page_pools",
+    "page_axis",
+    "parking_page",
     "paginate_cache",
     "park_slot",
     "demo_round_trip",

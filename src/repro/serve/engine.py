@@ -69,6 +69,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.transformer import moe_counts
+from repro.serve.disagg import is_paged, page_axis, page_pools, parking_page
 from repro.serve.paged import HostKVTier, KVPoolManager
 from repro.serve.scheduler import Scheduler
 
@@ -125,7 +127,7 @@ def _paged_dicts(tree):
 def _map_paged(cache, fn):
     """Rebuild a cache tree applying ``fn`` to every paged-attention dict."""
     if isinstance(cache, dict):
-        if "k_pages" in cache:
+        if is_paged(cache):
             return fn(cache)
         return {k: _map_paged(v, fn) for k, v in cache.items()}
     if isinstance(cache, list):
@@ -168,19 +170,39 @@ class Executor:
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.page_tokens = page_tokens
-        self.cache = model.init_cache(n_slots, max_seq, enc_len=enc_len)
         self.paged_kv = paged_kv
-        if paged_kv:
-            from repro.serve import disagg
 
-            paged_cache = disagg.paginate_cache(self.cache, page_tokens)
-            if not any("k_pages" in d for d in _paged_dicts(paged_cache)):
-                raise ValueError(
-                    f"paged_kv=True but the {model.cfg.family!r} stack has "
-                    "no self-attention KV caches to page (MLA/SSM caches "
-                    "stay dense) — the paged data plane would be a no-op")
-            self.cache = paged_cache
-        self._decode_fn = jax.jit(model.decode_step)
+        def fresh_cache():
+            cache = model.init_cache(n_slots, max_seq, enc_len=enc_len)
+            if paged_kv:
+                from repro.serve import disagg
+
+                cache = disagg.paginate_cache(cache, page_tokens)
+            return cache
+
+        # one program: the pools are made in place, never the dense cache
+        # and its re-paged copy side by side on the device
+        self.cache = jax.jit(fresh_cache)()
+        if paged_kv and not any(is_paged(d) for d in _paged_dicts(self.cache)):
+            raise ValueError(
+                f"paged_kv=True but the {model.cfg.family!r} stack has "
+                "no self-attention KV caches to page (SSM caches stay "
+                "dense) — the paged data plane would be a no-op")
+        # a model whose expert layers hold a share of the experts counts,
+        # per call, the assignments routed to held experts and the held
+        # experts hit (``transformer.moe_counts``); they come back with the
+        # tokens, in the same host sync
+        self.counted = moe_counts(self.cache) is not None
+        self.last_counts = None      # (moe_held, experts_hit) of the last call
+        if self.counted:
+            # named so its program is jit_decode_step, as the uncounted one
+            def decode_step(params, cache, tokens):
+                logits, cache = model.decode_step(params, cache, tokens)
+                return logits, cache, moe_counts(cache)
+
+            self._decode_fn = jax.jit(decode_step)
+        else:
+            self._decode_fn = jax.jit(model.decode_step)
         self.prefill_shapes: set[tuple] = set()   # one compile each
 
         # single-sequence prefill that scatters into one cache slot; in
@@ -193,6 +215,8 @@ class Executor:
             sub = model.init_cache(1, max_seq, enc_len=enc_len)
             logits, sub = model.prefill(params, {"tokens": tokens}, sub)
             cache2 = self._insert(cache, sub, slot, phys_pages, write_ok)
+            if self.counted:
+                return logits, cache2, moe_counts(sub)
             return logits, cache2
 
         self._prefill_fn = jax.jit(prefill_into_slot)
@@ -203,41 +227,46 @@ class Executor:
         """Prefill one admitted request into ``slot``; returns its first
         greedy token."""
         self.prefill_shapes.add(tuple(tokens.shape))
-        logits, self.cache = self._prefill_fn(self.params, self.cache,
-                                              tokens, slot, phys_pages,
-                                              write_ok)
+        out = self._prefill_fn(self.params, self.cache, tokens, slot,
+                               phys_pages, write_ok)
+        logits, self.cache = out[:2]
         argmax = jnp.argmax(logits[0, -1])
         with _span("serve.prefill.sync"):
-            return int(np.asarray(argmax))
+            if not self.counted:
+                return int(np.asarray(argmax))
+            argmax, self.last_counts = jax.device_get((argmax, out[2]))
+            return int(argmax)
 
-    def decode(self, last_tokens: np.ndarray) -> np.ndarray:
-        """One decode step over every slot; returns per-slot argmax."""
+    def decode(self, last_tokens: np.ndarray, **span_args) -> np.ndarray:
+        """One decode step over every slot; returns per-slot argmax.  A
+        counted model's span gains its ``moe_held`` and ``experts_hit``."""
         tokens = jnp.asarray(last_tokens)
-        with _span("serve.decode"):
-            logits, self.cache = self._decode_fn(self.params, self.cache,
-                                                 tokens)
+        span = _span("serve.decode", **span_args)
+        with span:
+            out = self._decode_fn(self.params, self.cache, tokens)
+            logits, self.cache = out[:2]
             argmax = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
             with _span("serve.decode.sync"):
-                return np.asarray(argmax)
+                if not self.counted:
+                    return np.asarray(argmax)
+                argmax, self.last_counts = jax.device_get((argmax, out[2]))
+            if span is not _NO_SPAN:
+                span.set_metadata(moe_held=int(self.last_counts[0]),
+                                  experts_hit=int(self.last_counts[1]))
+            return argmax
 
     # -- paged-pool device ops ---------------------------------------------------
     def fork_page(self, slot: int, j: int, src: int, dst: int) -> None:
         """Copy-on-write fork: copy physical page ``src`` → ``dst`` in every
         paged pool and point this slot's table entry ``j`` at the copy."""
         def fork(d):
-            kp, vp = d["k_pages"], d["v_pages"]
-            table = d["page_table"]
-            if kp.ndim == 4:
-                kp = kp.at[dst].set(kp[src])
-                vp = vp.at[dst].set(vp[src])
-                table = table.at[slot, j].set(dst)
-            else:                               # leading scan (layers) dim
-                kp = kp.at[:, dst].set(kp[:, src])
-                vp = vp.at[:, dst].set(vp[:, src])
-                table = table.at[:, slot, j].set(dst)
-            ro = d["page_ro"].at[..., dst].set(False)
-            out = dict(d, k_pages=kp, v_pages=vp, page_table=table,
-                       page_ro=ro)
+            lead = (slice(None),) * page_axis(d)
+            out = dict(d)
+            for pool in page_pools(d).values():
+                leaf = d[pool]
+                out[pool] = leaf.at[lead + (dst,)].set(leaf[lead + (src,)])
+            out["page_table"] = d["page_table"].at[lead + (slot, j)].set(dst)
+            out["page_ro"] = d["page_ro"].at[..., dst].set(False)
             if "page_hot" in d:
                 out["page_hot"] = d["page_hot"].at[..., dst].set(True)
             return out
@@ -272,31 +301,30 @@ class Executor:
         self.cache = _map_paged(self.cache, mark)
 
     # -- tiered payload migration -------------------------------------------
+    def _pool_leaves(self):
+        """Every page pool of the cache, with its page axis, in the fixed
+        walk order the payload gather and scatter share."""
+        for d in _paged_dicts(self.cache):
+            if is_paged(d):
+                for pool in page_pools(d).values():
+                    yield d, pool, page_axis(d)
+
     @property
     def page_payload_dtype(self):
         """Dtype of the concatenated per-page payload (the pools' dtype)."""
-        for d in _paged_dicts(self.cache):
-            if "k_pages" in d:
-                return d["k_pages"].dtype
+        for d, pool, _ in self._pool_leaves():
+            return d[pool].dtype
         raise ValueError("no paged pools in this cache")
 
     @property
     def page_payload_elems(self) -> int:
-        """Elements in one page's full payload: every paged pool's K and V
-        bytes for that page concatenated (a scan-stacked pool contributes
-        all its layers), so one host-tier slot round-trips one logical KV
-        page no matter how the stack is laid out."""
-        n = 0
-        for d in _paged_dicts(self.cache):
-            if "k_pages" not in d:
-                continue
-            for key in ("k_pages", "v_pages"):
-                leaf = d[key]
-                if leaf.ndim == 4:                  # (pages, pt, KV, hd)
-                    n += leaf.shape[1] * leaf.shape[2] * leaf.shape[3]
-                else:                               # (L, pages, pt, KV, hd)
-                    n += (leaf.shape[0] * leaf.shape[2] * leaf.shape[3]
-                          * leaf.shape[4])
+        """Elements in one page's full payload: every paged pool's bytes
+        for that page concatenated (K and V, or the latent pair; a
+        scan-stacked pool contributes all its layers), so one host-tier
+        slot round-trips one logical KV page no matter how the stack is
+        laid out."""
+        n = sum(d[pool].size // d[pool].shape[ax]
+                for d, pool, ax in self._pool_leaves())
         if not n:
             raise ValueError("no paged pools in this cache")
         return n
@@ -311,17 +339,9 @@ class Executor:
         pages = list(pages)
         idx = jnp.asarray(pages, jnp.int32)
         dt = self.page_payload_dtype
-        parts = []
-        for d in _paged_dicts(self.cache):
-            if "k_pages" not in d:
-                continue
-            for key in ("k_pages", "v_pages"):
-                leaf = d[key]
-                if leaf.ndim == 4:
-                    part = leaf[idx]
-                else:
-                    part = jnp.moveaxis(leaf[:, idx], 0, 1)
-                parts.append(part.reshape(len(pages), -1).astype(dt))
+        parts = [jnp.moveaxis(jnp.take(d[pool], idx, axis=ax), ax, 0)
+                 .reshape(len(pages), -1).astype(dt)
+                 for d, pool, ax in self._pool_leaves()]
         return jnp.concatenate(parts, axis=1)
 
     def scatter_page_payloads(self, pages, payloads) -> None:
@@ -335,21 +355,14 @@ class Executor:
 
         def put(d):
             out = dict(d)
-            for key in ("k_pages", "v_pages"):
-                leaf = d[key]
-                if leaf.ndim == 4:
-                    shape = (len(pages),) + leaf.shape[1:]
-                    take = shape[1] * shape[2] * shape[3]
-                    chunk = payloads[:, cur[0]:cur[0] + take]
-                    out[key] = leaf.at[idx].set(
-                        chunk.reshape(shape).astype(leaf.dtype))
-                else:
-                    lead = leaf.shape[0]
-                    shape = (len(pages), lead) + leaf.shape[2:]
-                    take = lead * shape[2] * shape[3] * shape[4]
-                    chunk = payloads[:, cur[0]:cur[0] + take]
-                    out[key] = leaf.at[:, idx].set(jnp.moveaxis(
-                        chunk.reshape(shape).astype(leaf.dtype), 1, 0))
+            ax = page_axis(d)
+            for pool in page_pools(d).values():
+                leaf = d[pool]
+                shape = (len(pages),) + leaf.shape[:ax] + leaf.shape[ax + 1:]
+                take = leaf.size // leaf.shape[ax]
+                chunk = payloads[:, cur[0]:cur[0] + take].reshape(shape)
+                out[pool] = leaf.at[(slice(None),) * ax + (idx,)].set(
+                    jnp.moveaxis(chunk.astype(leaf.dtype), 0, ax))
                 cur[0] += take
             return out
 
@@ -381,7 +394,7 @@ class Executor:
             if isinstance(tree, dict):
                 out = {k: (v if k == "step" else restep(v))
                        for k, v in tree.items()}
-                if "step" in out and "k_pages" not in out:
+                if "step" in out and not is_paged(out):
                     out["step"] = out["step"].at[slot].set(pos)
                 return out
             if isinstance(tree, list):
@@ -404,7 +417,7 @@ class Executor:
         scatter through the page table, everything else along the batch
         axis)."""
         if isinstance(full, dict):
-            if "k_pages" in full:
+            if is_paged(full):
                 return self._insert_paged_attn(full, one, slot, phys_pages,
                                                write_ok)
             return {key: self._insert(full[key], one[key], slot, phys_pages,
@@ -416,21 +429,21 @@ class Executor:
         return _insert_row(full, one, slot, self.n_slots)
 
     def _insert_paged_attn(self, full, one, slot, phys_pages, write_ok):
-        """Scatter a dense (1, S, KV, hd) prefill KV into the slot's physical
-        pages and point the slot's page-table row at them.  Pages with
-        ``write_ok=False`` are *shared* — the donor already holds their
-        prefix KV — so their scatter is routed to the parking page while the
-        table still maps them."""
+        """Scatter a dense (1, S, *feature) prefill cache -- GQA K and V, or
+        the latent pair -- into the slot's physical pages and point the
+        slot's page-table row at them.  Pages with ``write_ok=False`` are
+        *shared* — the donor already holds their prefix KV — so their
+        scatter is routed to the parking page while the table still maps
+        them."""
         pt = self.page_tokens
-        park = full["k_pages"].shape[-4] - 1
-        dest = jnp.where(write_ok, phys_pages, park)
+        dest = jnp.where(write_ok, phys_pages, parking_page(full))
+        ax = page_axis(full)
 
         def repage_scatter(pool, dense):
-            *lead, _, s, kv, hd = dense.shape
-            d = dense.reshape(*lead, s // pt, pt, kv, hd).astype(pool.dtype)
-            if pool.ndim == 4:
-                return pool.at[dest].set(d)
-            return pool.at[:, dest].set(d)   # leading scan dim
+            lead, (_, s), feat = (dense.shape[:ax], dense.shape[ax:ax + 2],
+                                  dense.shape[ax + 2:])
+            d = dense.reshape(*lead, s // pt, pt, *feat).astype(pool.dtype)
+            return pool.at[(slice(None),) * ax + (dest,)].set(d)
 
         table, pos = full["page_table"], full["pos"]
         if table.ndim == 2:
@@ -439,13 +452,10 @@ class Executor:
         else:
             table = table.at[:, slot].set(phys_pages)
             pos = pos.at[:, slot].set(one["pos"][:, 0])
-        return dict(
-            full,
-            k_pages=repage_scatter(full["k_pages"], one["k"]),
-            v_pages=repage_scatter(full["v_pages"], one["v"]),
-            page_table=table,
-            pos=pos,
-        )
+        out = dict(full, page_table=table, pos=pos)
+        for dense, pool in page_pools(full).items():
+            out[pool] = repage_scatter(full[pool], one[dense])
+        return out
 
 
 class ServeEngine:
@@ -525,6 +535,12 @@ class ServeEngine:
         self._tick_pages_reserved = 0
         self._tick_pages_used = 0
         self._await_out: list = []   # first token made this tick: t_out due
+        # running sums of a counted model's expert counters, per phase
+        self.moe_sums = ({f"{phase}_{k}": 0 for phase in ("prefill", "decode")
+                          for k in ("moe_held", "experts_hit", "calls")}
+                         if self.executor.counted else None)
+        if self.moe_sums is not None:
+            self.moe_sums["decode_live_tokens"] = 0
 
     # -- compat views ------------------------------------------------------------
     @property
@@ -583,7 +599,18 @@ class ServeEngine:
                 # host bookkeeping and device state disagree
                 for slot in sorted(self._active):
                     self.pool.assert_resident(self.slot_pages[slot])
-            nxt = self.executor.decode(self._last_tokens)
+            span_args = {}
+            if self.executor.counted:
+                # the rows that commit a token, and the keys they attend:
+                # each one's context, its new token in
+                rows = [s for s in self.slot_req
+                        if not self.tiered or s in self._active]
+                span_args = {"rows": len(rows), "live_tokens": sum(
+                    self.slot_pos[s] for s in rows)}
+            nxt = self.executor.decode(self._last_tokens, **span_args)
+            if self.executor.counted:
+                self._count("decode", self.executor.last_counts,
+                            span_args["live_tokens"])
             with _span("serve.commit"):
                 for slot in list(self.slot_req):
                     if self.tiered and slot not in self._active:
@@ -706,9 +733,22 @@ class ServeEngine:
                            demotions=self.pool.demotions,
                            promotions=self.pool.promotions,
                            tier_stale_drops=int(self.tier.err_count))
+        if self.moe_sums is not None:
+            out.update(self.moe_sums)
         return out
 
     # -- internals --------------------------------------------------------------
+    def _count(self, phase: str, counts, live_tokens: int = 0) -> tuple[int, int]:
+        """Add one call's expert counters to the running sums."""
+        held, hit = int(counts[0]), int(counts[1])
+        sums = self.moe_sums
+        sums[f"{phase}_moe_held"] += held
+        sums[f"{phase}_experts_hit"] += hit
+        sums[f"{phase}_calls"] += 1
+        if phase == "decode":
+            sums["decode_live_tokens"] += live_tokens
+        return held, hit
+
     def _completion(self, req: Request, tokens: list, finished: bool,
                     entry) -> Completion:
         if entry is None:
@@ -817,11 +857,16 @@ class ServeEngine:
         else:
             phys_arg = jnp.zeros((0,), jnp.int32)
             ok_arg = jnp.zeros((0,), bool)
-        with _span("serve.prefill", rid=req.rid, prompt_len=len(req.prompt),
-                   slot=slot):
+        span = _span("serve.prefill", rid=req.rid, prompt_len=len(req.prompt),
+                     slot=slot)
+        with span:
             entry.t_admit, entry.t_out = time.perf_counter(), 0.0
             tokens = jnp.asarray(req.prompt, jnp.int32)[None]
             first = self.executor.prefill(tokens, slot, phys_arg, ok_arg)
+            if self.executor.counted:
+                held, hit = self._count("prefill", self.executor.last_counts)
+                if span is not _NO_SPAN:
+                    span.set_metadata(moe_held=held, experts_hit=hit)
         entry.t_first = time.perf_counter()
         self._await_out.append(entry)
         self.slot_free[slot] = False

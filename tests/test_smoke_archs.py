@@ -52,6 +52,7 @@ def test_full_config_matches_assignment(arch):
         "mamba2-370m": (48, 1024, 1, 1, 0, 50280),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 16384, 202048),
         "deepseek-v2-236b": (60, 5120, 128, 128, 1536, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
         "jamba-v0.1-52b": (32, 4096, 32, 8, 14336, 65536),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab)
