@@ -195,6 +195,46 @@ def _page_routes(cache: dict, cols: Array, n_pages: int, pt: int):
     return phys, cols % pt, gather_table
 
 
+def _paged_write(cache: dict, new: dict, cols: Array):
+    """Scatter each pool's new tokens (``new``: pool name -> (B, S,
+    *feature)) into each row's pages (:func:`_page_routes`) and gather every
+    row's logical view of each pool through its page table.  Returns the
+    new cache and the views, (B, pages·pt, *feature) each, in ``new``'s
+    order.
+
+    A page is ``pt`` rows of one token each, or, in a pool of a narrow
+    feature, rows of several tokens side by side
+    (:func:`repro.serve.disagg.page_shape`); a token's window starts at its
+    row and its lane offset in that row.  A cache that carries a ``layer``
+    index holds its pools stacked over the scanned layers
+    (``transformer.apply_stack`` carries them through the layer scan): the
+    scatter and the gather index the stacked pool at that layer directly,
+    so no layer's pool is sliced out or written back."""
+    lead = (cache["layer"],) if "layer" in cache else ()
+    k = len(lead)
+    first = next(iter(new))
+    pool = cache[first]
+    pt = pool.shape[k + 1] * (pool.shape[-1] // new[first].shape[-1])
+    phys, in_page, gather_table = _page_routes(cache, cols, pool.shape[k], pt)
+    B = cols.shape[0]
+    out, views = dict(cache), []
+    for name, val in new.items():
+        pool, val = cache[name], val.astype(cache[name].dtype)
+        per_row = pool.shape[-1] // val.shape[-1]   # tokens side by side
+        if per_row == 1:
+            pool = pool.at[lead + (phys, in_page)].set(val)
+        else:
+            # element by element, from the token's lane offset in its row
+            # (a window that starts inside the row is not a native scatter)
+            f = val.shape[-1]
+            lanes = (in_page % per_row * f)[..., None] + jnp.arange(f)
+            pool = pool.at[lead + (phys[..., None], (in_page // per_row)[..., None],
+                                   lanes)].set(val)
+        out[name] = pool
+        views.append(pool[lead + (gather_table,)].reshape(B, -1, *val.shape[2:]))
+    return out, views
+
+
 def gqa_attention(
     params: dict,
     x: Array,
@@ -256,14 +296,9 @@ def gqa_attention(
             # table (the decode-side PagedKVWindow layout).  New tokens
             # scatter into the row's current physical page; attention
             # gathers the row's pages back into a contiguous logical view.
-            kp, vp = cache["k_pages"], cache["v_pages"]
-            phys, in_page, gather_table = _page_routes(cache, cols, kp.shape[0],
-                                                       kp.shape[1])
-            ckp = kp.at[phys, in_page].set(k.astype(kp.dtype))
-            cvp = vp.at[phys, in_page].set(v.astype(vp.dtype))
-            new_cache = dict(cache, k_pages=ckp, v_pages=cvp, pos=pos + S)
-            ck = ckp[gather_table].reshape(B, -1, KV, hd)  # (B, pages·pt, KV, hd)
-            cv = cvp[gather_table].reshape(B, -1, KV, hd)
+            new_cache, (ck, cv) = _paged_write(
+                cache, {"k_pages": k, "v_pages": v}, cols)  # (B, pages·pt, KV, hd)
+            new_cache["pos"] = pos + S
             ck = logical_constraint(ck, "batch", "kv_seq", "kv_heads", None)
             cv = logical_constraint(cv, "batch", "kv_seq", "kv_heads", None)
         else:
@@ -510,19 +545,6 @@ def _mla_absorbed(q_nope, q_rope, c_all, kr_all, params, *, qpos, scale):
     return jnp.einsum("bshr,rhv->bshv", ctx, params["w_uv"].astype(dt))
 
 
-def _mla_paged_write(cache, c_kv, k_rope, cols):
-    """Scatter the new latents into each row's pages (:func:`_page_routes`)
-    and gather every row's logical latent view through its page table."""
-    cp, kp = cache["ckv_pages"], cache["kr_pages"]
-    phys, in_page, gather_table = _page_routes(cache, cols, cp.shape[0], cp.shape[1])
-    cp = cp.at[phys, in_page].set(c_kv.astype(cp.dtype))
-    kp = kp.at[phys, in_page].set(k_rope.astype(kp.dtype))
-    B = cols.shape[0]
-    c_all = cp[gather_table].reshape(B, -1, cp.shape[-1])
-    kr_all = kp[gather_table].reshape(B, -1, kp.shape[-1])
-    return dict(cache, ckv_pages=cp, kr_pages=kp), c_all, kr_all
-
-
 def mla_attention(
     params: dict,
     x: Array,
@@ -566,7 +588,8 @@ def mla_attention(
         pos = cache["pos"]  # (B,)
         cols = pos[:, None] + jnp.arange(S)[None, :]
         if "ckv_pages" in cache:
-            new_cache, c_all, kr_all = _mla_paged_write(cache, c_kv, k_rope, cols)
+            new_cache, (c_all, kr_all) = _paged_write(
+                cache, {"ckv_pages": c_kv, "kr_pages": k_rope}, cols)
         else:
             rows = jnp.arange(B)[:, None]
             c_all = cache["c_kv"].at[rows, cols].set(c_kv.astype(cache["c_kv"].dtype))

@@ -252,6 +252,24 @@ def parking_page(d) -> int:
     return d["page_ro"].shape[-1] - 1
 
 
+#: lanes of a TPU vector register, the minor tile of its memory layout
+LANES = 128
+
+
+def page_shape(page_tokens: int, feature: tuple) -> tuple:
+    """The shape of one page of a pool of ``feature``-shaped tokens:
+    ``(page_tokens, *feature)``, or, for a 1-D feature that divides the
+    lanes (MLA's 64-wide rope key), rows of ``LANES`` that each hold
+    several tokens side by side.  A TPU lays a ``(…, pages, pt, 64)`` pool
+    out with its pages minor, so every paged write or gather would first
+    copy the whole pool into another layout; rows of ``LANES`` keep each
+    page whole in its own tiles."""
+    if (len(feature) == 1 and LANES % feature[0] == 0
+            and page_tokens * feature[0] % LANES == 0):
+        return (page_tokens * feature[0] // LANES, LANES)
+    return (page_tokens, *feature)
+
+
 def paginate_cache(cache, page_tokens: int):
     """Convert every dense self-attention leaf group of a stack cache -- GQA
     ``{k, v, pos}`` or MLA's latent ``{c_kv, k_rope, pos}`` -- into the
@@ -260,7 +278,8 @@ def paginate_cache(cache, page_tokens: int):
     and ``pos``.
 
     Dense leaves of shape ``(…, B, S, *feature)`` become physical pools
-    of ``B·S/pt`` allocatable pages **plus one parking page**; every
+    of ``B·S/pt`` allocatable pages (:func:`page_shape`) **plus one
+    parking page**; every
     page-table entry starts pointing at the parking page, and the engine's
     :class:`PageAllocator` (which hands out ids ``0 … B·S/pt − 1``) wires
     rows to real pages at slot admission.  The parking page matters: idle
@@ -300,9 +319,9 @@ def paginate_cache(cache, page_tokens: int):
         lead_shape = first.shape[:lead]
 
         def repage(x):
-            feat = x.shape[lead + 2:]
-            pool = x.reshape(*lead_shape, n_alloc, page_tokens, *feat)
-            park = jnp.zeros((*lead_shape, 1, page_tokens, *feat), pool.dtype)
+            page = page_shape(page_tokens, x.shape[lead + 2:])
+            pool = x.reshape(*lead_shape, n_alloc, *page)
+            park = jnp.zeros((*lead_shape, 1, *page), pool.dtype)
             return jnp.concatenate([pool, park], axis=lead)
 
         out = {pool: repage(cache[dense]) for dense, pool in leaves.items()}
@@ -470,6 +489,8 @@ __all__ = [
     "pool_stats",
     "PageAllocator",
     "PAGED_LEAVES",
+    "LANES",
+    "page_shape",
     "is_paged",
     "page_pools",
     "page_axis",

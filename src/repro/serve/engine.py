@@ -155,12 +155,112 @@ def _insert_row(full: Array, one: Array, slot, n_slots: int) -> Array:
     return full
 
 
+def executor_programs(model, *, n_slots: int, max_seq: int, enc_len: int = 0,
+                      paged_kv: bool = False, page_tokens: int = 16):
+    """The executor's programs, jitted: ``(fresh_cache, decode_step,
+    prefill_into_slot)``.
+
+    ``fresh_cache()`` builds the batched cache in one program (paged, the
+    pools are made in place, never the dense cache and its re-paged copy
+    side by side on the device).  ``decode_step(params, cache, tokens)`` and
+    ``prefill_into_slot(params, cache, tokens, slot, phys_pages, write_ok)``
+    return ``(logits, cache, counts)``: ``counts`` is the call's expert
+    counters (``transformer.moe_counts``), or None for a model without an
+    expert share.  Both **donate** the cache: the page pools are written in
+    place, and the cache passed in is dead once the call is made."""
+    def fresh_cache():
+        cache = model.init_cache(n_slots, max_seq, enc_len=enc_len)
+        if paged_kv:
+            from repro.serve import disagg
+
+            cache = disagg.paginate_cache(cache, page_tokens)
+        return cache
+
+    def decode_step(params, cache, tokens):
+        logits, cache = model.decode_step(params, cache, tokens)
+        return logits, cache, moe_counts(cache)
+
+    # single-sequence prefill that scatters into one cache slot; in paged
+    # mode the dense prefill KV is re-paged into the slot's physical pages
+    # (write-masked pages land on the parking page — they are shared, their
+    # contents already prefilled by the donor) and the slot's page-table
+    # row is wired up
+    def prefill_into_slot(params, cache, tokens, slot, phys_pages, write_ok):
+        sub = model.init_cache(1, max_seq, enc_len=enc_len)
+        logits, sub = model.prefill(params, {"tokens": tokens}, sub)
+        cache = _insert(cache, sub, slot, phys_pages, write_ok, n_slots)
+        return logits, cache, moe_counts(sub)
+
+    return (jax.jit(fresh_cache), jax.jit(decode_step, donate_argnums=1),
+            jax.jit(prefill_into_slot, donate_argnums=1))
+
+
+def _insert(full, one, slot, phys_pages, write_ok, n_slots: int):
+    """Insert the freshly prefilled 1-row cache ``one`` into slot ``slot``
+    of the engine cache ``full`` (recursive walk; paged attention dicts
+    scatter through the page table, everything else along the batch
+    axis)."""
+    if isinstance(full, dict):
+        if is_paged(full):
+            return _insert_paged_attn(full, one, slot, phys_pages, write_ok)
+        return {key: _insert(full[key], one[key], slot, phys_pages, write_ok,
+                             n_slots)
+                for key in full}
+    if isinstance(full, list):
+        return [_insert(f, o, slot, phys_pages, write_ok, n_slots)
+                for f, o in zip(full, one)]
+    return _insert_row(full, one, slot, n_slots)
+
+
+def _insert_paged_attn(full, one, slot, phys_pages, write_ok):
+    """Scatter a dense (1, S, *feature) prefill cache -- GQA K and V, or the
+    latent pair -- into the slot's physical pages and point the slot's
+    page-table row at them.  Pages with ``write_ok=False`` are *shared* —
+    the donor already holds their prefix KV — so their scatter is routed to
+    the parking page while the table still maps them."""
+    dest = jnp.where(write_ok, phys_pages, parking_page(full))
+    ax = page_axis(full)
+
+    def repage_scatter(pool, dense):
+        # page by page, each an in-place dynamic_update_slice of the page
+        # in every layer: a scatter of whole pages made the compiler copy
+        # the pool into another tiling and back, and a scatter of rows ran
+        # 5-7x slower than this loop (TPU v5e: 18.8 against 2.7 ms for
+        # StarCoder2-3B's pools, 31.3 against 5.7 ms for DeepSeek-V2-Lite's)
+        pages = dense.reshape(*dense.shape[:ax], -1, *pool.shape[ax + 1:])
+        pages = pages.astype(pool.dtype)
+
+        def put(j, pool):
+            page = jax.lax.dynamic_slice_in_dim(pages, j, 1, axis=ax)
+            start = (0,) * ax + (dest[j],) + (0,) * (pool.ndim - ax - 1)
+            return jax.lax.dynamic_update_slice(pool, page, start)
+
+        return jax.lax.fori_loop(0, pages.shape[ax], put, pool)
+
+    table, pos = full["page_table"], full["pos"]
+    if table.ndim == 2:
+        table = table.at[slot].set(phys_pages)
+        pos = pos.at[slot].set(one["pos"][0])
+    else:
+        table = table.at[:, slot].set(phys_pages)
+        pos = pos.at[:, slot].set(one["pos"][:, 0])
+    out = dict(full, page_table=table, pos=pos)
+    for dense, pool in page_pools(full).items():
+        out[pool] = repage_scatter(full[pool], one[dense])
+    return out
+
+
 class Executor:
     """The execution layer: batched cache + jitted prefill/decode.
 
     Decisions live elsewhere — the scheduler picks *what* runs, the pool
     manager picks *which pages* back it; the executor is handed a slot, a
-    physical-page row, and a per-page write mask, and runs the model."""
+    physical-page row, and a per-page write mask, and runs the model.
+
+    The cache is donated to every program that returns it
+    (:func:`executor_programs`): ``self.cache`` is replaced by each call's
+    result, and no caller may keep a reference to it across ``prefill`` or
+    ``decode``."""
 
     def __init__(self, model, params, *, n_slots: int, max_seq: int,
                  enc_len: int = 0, paged_kv: bool = False,
@@ -171,18 +271,10 @@ class Executor:
         self.max_seq = max_seq
         self.page_tokens = page_tokens
         self.paged_kv = paged_kv
-
-        def fresh_cache():
-            cache = model.init_cache(n_slots, max_seq, enc_len=enc_len)
-            if paged_kv:
-                from repro.serve import disagg
-
-                cache = disagg.paginate_cache(cache, page_tokens)
-            return cache
-
-        # one program: the pools are made in place, never the dense cache
-        # and its re-paged copy side by side on the device
-        self.cache = jax.jit(fresh_cache)()
+        fresh_cache, self._decode_jit, self._prefill_fn = executor_programs(
+            model, n_slots=n_slots, max_seq=max_seq, enc_len=enc_len,
+            paged_kv=paged_kv, page_tokens=page_tokens)
+        self.cache = fresh_cache()
         if paged_kv and not any(is_paged(d) for d in _paged_dicts(self.cache)):
             raise ValueError(
                 f"paged_kv=True but the {model.cfg.family!r} stack has "
@@ -194,32 +286,31 @@ class Executor:
         # tokens, in the same host sync
         self.counted = moe_counts(self.cache) is not None
         self.last_counts = None      # (moe_held, experts_hit) of the last call
-        if self.counted:
-            # named so its program is jit_decode_step, as the uncounted one
-            def decode_step(params, cache, tokens):
-                logits, cache = model.decode_step(params, cache, tokens)
-                return logits, cache, moe_counts(cache)
-
-            self._decode_fn = jax.jit(decode_step)
-        else:
-            self._decode_fn = jax.jit(model.decode_step)
         self.prefill_shapes: set[tuple] = set()   # one compile each
+        # the decode program's one shape is compiled once, on first use, and
+        # that executable runs every step (its memory analysis is read
+        # without a second compile)
+        self._decode_exe = None
 
-        # single-sequence prefill that scatters into one cache slot; in
-        # paged mode the dense prefill KV is re-paged into the slot's
-        # physical pages (write-masked pages land on the parking page —
-        # they are shared, their contents already prefilled by the donor)
-        # and the slot's page-table row is wired up
-        def prefill_into_slot(params, cache, tokens, slot, phys_pages,
-                              write_ok):
-            sub = model.init_cache(1, max_seq, enc_len=enc_len)
-            logits, sub = model.prefill(params, {"tokens": tokens}, sub)
-            cache2 = self._insert(cache, sub, slot, phys_pages, write_ok)
-            if self.counted:
-                return logits, cache2, moe_counts(sub)
-            return logits, cache2
+    def _decode_fn(self, params, cache, tokens):
+        if self._decode_exe is None:
+            self._decode_exe = self._decode_jit.lower(params, cache,
+                                                      tokens).compile()
+        return self._decode_exe(params, cache, tokens)
 
-        self._prefill_fn = jax.jit(prefill_into_slot)
+    @property
+    def pool_bytes(self) -> int:
+        """Bytes of the cache's page pools (0 unpaged)."""
+        return sum(d[pool].nbytes for d, pool, _ in self._pool_leaves())
+
+    @property
+    def decode_pool_aliased_bytes(self) -> int | None:
+        """Bytes of the decode program's inputs its outputs reuse in place
+        (``memory_analysis().alias_size_in_bytes``), or None before the
+        first decode compiled it."""
+        if self._decode_exe is None:
+            return None
+        return self._decode_exe.memory_analysis().alias_size_in_bytes
 
     # -- the two model calls ----------------------------------------------------
     def prefill(self, tokens: Array, slot: int, phys_pages: Array,
@@ -227,14 +318,13 @@ class Executor:
         """Prefill one admitted request into ``slot``; returns its first
         greedy token."""
         self.prefill_shapes.add(tuple(tokens.shape))
-        out = self._prefill_fn(self.params, self.cache, tokens, slot,
-                               phys_pages, write_ok)
-        logits, self.cache = out[:2]
+        logits, self.cache, counts = self._prefill_fn(
+            self.params, self.cache, tokens, slot, phys_pages, write_ok)
         argmax = jnp.argmax(logits[0, -1])
         with _span("serve.prefill.sync"):
             if not self.counted:
                 return int(np.asarray(argmax))
-            argmax, self.last_counts = jax.device_get((argmax, out[2]))
+            argmax, self.last_counts = jax.device_get((argmax, counts))
             return int(argmax)
 
     def decode(self, last_tokens: np.ndarray, **span_args) -> np.ndarray:
@@ -243,13 +333,13 @@ class Executor:
         tokens = jnp.asarray(last_tokens)
         span = _span("serve.decode", **span_args)
         with span:
-            out = self._decode_fn(self.params, self.cache, tokens)
-            logits, self.cache = out[:2]
+            logits, self.cache, counts = self._decode_fn(self.params,
+                                                         self.cache, tokens)
             argmax = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
             with _span("serve.decode.sync"):
                 if not self.counted:
                     return np.asarray(argmax)
-                argmax, self.last_counts = jax.device_get((argmax, out[2]))
+                argmax, self.last_counts = jax.device_get((argmax, counts))
             if span is not _NO_SPAN:
                 span.set_metadata(moe_held=int(self.last_counts[0]),
                                   experts_hit=int(self.last_counts[1]))
@@ -409,53 +499,6 @@ class Executor:
         from repro.serve import disagg
 
         self.cache = disagg.park_slot(self.cache, slot)
-
-    # -- cache insertion ---------------------------------------------------------
-    def _insert(self, full, one, slot, phys_pages, write_ok):
-        """Insert the freshly prefilled 1-row cache ``one`` into slot ``slot``
-        of the engine cache ``full`` (recursive walk; paged attention dicts
-        scatter through the page table, everything else along the batch
-        axis)."""
-        if isinstance(full, dict):
-            if is_paged(full):
-                return self._insert_paged_attn(full, one, slot, phys_pages,
-                                               write_ok)
-            return {key: self._insert(full[key], one[key], slot, phys_pages,
-                                      write_ok)
-                    for key in full}
-        if isinstance(full, list):
-            return [self._insert(f, o, slot, phys_pages, write_ok)
-                    for f, o in zip(full, one)]
-        return _insert_row(full, one, slot, self.n_slots)
-
-    def _insert_paged_attn(self, full, one, slot, phys_pages, write_ok):
-        """Scatter a dense (1, S, *feature) prefill cache -- GQA K and V, or
-        the latent pair -- into the slot's physical pages and point the
-        slot's page-table row at them.  Pages with ``write_ok=False`` are
-        *shared* — the donor already holds their prefix KV — so their
-        scatter is routed to the parking page while the table still maps
-        them."""
-        pt = self.page_tokens
-        dest = jnp.where(write_ok, phys_pages, parking_page(full))
-        ax = page_axis(full)
-
-        def repage_scatter(pool, dense):
-            lead, (_, s), feat = (dense.shape[:ax], dense.shape[ax:ax + 2],
-                                  dense.shape[ax + 2:])
-            d = dense.reshape(*lead, s // pt, pt, *feat).astype(pool.dtype)
-            return pool.at[(slice(None),) * ax + (dest,)].set(d)
-
-        table, pos = full["page_table"], full["pos"]
-        if table.ndim == 2:
-            table = table.at[slot].set(phys_pages)
-            pos = pos.at[slot].set(one["pos"][0])
-        else:
-            table = table.at[:, slot].set(phys_pages)
-            pos = pos.at[:, slot].set(one["pos"][:, 0])
-        out = dict(full, page_table=table, pos=pos)
-        for dense, pool in page_pools(full).items():
-            out[pool] = repage_scatter(full[pool], one[dense])
-        return out
 
 
 class ServeEngine:
@@ -724,7 +767,10 @@ class ServeEngine:
                        cow_debt=self.pool.cow_debt,
                        pages_used=self._pages_used(),
                        tick_pages_reserved=self._tick_pages_reserved,
-                       tick_pages_used=self._tick_pages_used)
+                       tick_pages_used=self._tick_pages_used,
+                       pool_bytes=self.executor.pool_bytes,
+                       decode_pool_aliased_bytes=(
+                           self.executor.decode_pool_aliased_bytes))
             if self.tiered:
                 out.update(host_pages=self.pool.host.capacity,
                            host_pages_free=self.pool.host.n_free,

@@ -7,8 +7,10 @@ process at a time may load the TPU library.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve.disagg import paginate_cache
+from repro.serve.engine import executor_programs
 
 #: HBM the v5e compiler lets one program use ("... of 15.75G hbm").
 V5E_HBM_BYTES = int(15.75 * 2**30)
@@ -97,3 +100,63 @@ def test_one_slot_prefill_fits_hbm(served, one_chip):
     compiled = jax.jit(model.prefill).lower(
         params, {"tokens": tokens}, cache).compile()
     assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+#: each serving cell's model and engine size: (n_slots, max_seq), the
+#: prompt length its prefill is compiled for, and the parent's decode
+#: temporaries (GiB), which the in-place pools must not exceed
+CELLS = {
+    "starcoder2-3b": ((32, 4096), 2048, 0.126),
+    "deepseek-v2-lite": ((16, 8192), 1024, 0.475),
+}
+
+
+def _cell_model(arch):
+    cfg = get_config(arch).replace(dtype="bfloat16", param_dtype="bfloat16")
+    if cfg.moe is not None:       # the chip's share: 8 of the 64 experts
+        cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, experts_held=(0, 8)))
+    return build_model(cfg)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", sorted(CELLS))
+def test_executor_writes_the_pools_in_place(one_chip, arch, program):
+    """The executor's decode and prefill_into_slot at each serving cell's
+    size: the donated cache's page pools alias their outputs whole, and
+    no pool-shaped copy is left in the compiled program.  A decode
+    returns no pool bytes it does not alias (``output_size_in_bytes``
+    counts the aliased outputs too, so the check is on the difference),
+    and needs no more temporaries than before the pools were carried."""
+    (n_slots, max_seq), prompt, parent_temp_gib = CELLS[arch]
+    model = _cell_model(arch)
+    params = _on(jax.eval_shape(model.init, jax.random.PRNGKey(0)), one_chip)
+    fresh, decode, prefill = executor_programs(
+        model, n_slots=n_slots, max_seq=max_seq, paged_kv=True,
+        page_tokens=PAGE_TOKENS)
+    cache = _on(jax.eval_shape(fresh), one_chip)
+    pools = {jax.tree_util.keystr(path): x
+             for path, x in jax.tree_util.tree_leaves_with_path(cache)
+             if jax.tree_util.keystr(path).endswith("_pages']")}
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in pools.values())
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    if program == "decode":
+        compiled = decode.lower(params, cache,
+                                spec((n_slots, 1), jnp.int32)).compile()
+    else:
+        pages = max_seq // PAGE_TOKENS
+        compiled = prefill.lower(
+            params, cache, spec((1, prompt), jnp.int32), spec((), jnp.int32),
+            spec((pages,), jnp.int32), spec((pages,), jnp.bool_)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes
+    if program == "decode":
+        assert m.output_size_in_bytes - m.alias_size_in_bytes < 0.01 * pool_bytes
+        assert m.temp_size_in_bytes <= parent_temp_gib * 2**30
+    # a pool, or one layer of a pool stacked over the scanned layers
+    shapes = {x.shape for x in pools.values()} | {
+        x.shape[1:] for key, x in pools.items() if key.startswith("['scan']")}
+    dims = "|".join(re.escape(",".join(map(str, s))) for s in shapes)
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r" copy(-start)?\(", line)
+              and re.search(rf"= \(?\w+\[({dims})\]", line)]
+    assert not copies, copies
