@@ -5,7 +5,9 @@ Shapes (batch B, sequence S, query heads H, kv heads KV, head_dim hd):
 
 * weights: wq (d, H, hd), wk/wv (d, KV, hd), wo (H, hd, d)
 * caches:  k/v (B, S_max, KV, hd); MLA caches the *compressed* (c_kv, k_rope)
-  pair instead — the memory win that defines MLA.
+  pair instead — the memory win that defines MLA.  Either cache may be
+  paged (``models/paged_cache.py``, which owns that layout): the decode
+  path then writes and reads it through :func:`paged_cache.paged_write`.
 
 The blockwise path (scan over KV blocks with running max/denominator) is the
 pure-JAX oracle for the Pallas flash kernel in ``repro/kernels/flash_attention``
@@ -19,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models import layers
+from repro.models import layers, paged_cache
 from repro.sharding import logical_constraint
 
 Array = jax.Array
@@ -152,89 +154,6 @@ def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
 # ---------------------------------------------------------------------------
 
 
-def _page_routes(cache: dict, cols: Array, n_pages: int, pt: int):
-    """Where a paged cache's new tokens land and what its rows read.
-
-    ``cols`` (B, S) are the new tokens' positions; the pool has ``n_pages``
-    physical pages (the last is the parking page) of ``pt`` tokens.
-    Returns ``(phys, in_page, gather_table)``: each new token's physical
-    page and offset in it, and the page table the attention gather reads
-    through.  A row at ``pos == max_seq`` has no page for the new token;
-    its scatter goes to an out-of-range physical id so it is dropped -- the
-    same silent OOB-write drop the dense layout gives.  The same rules hold
-    for GQA ``{k_pages, v_pages}`` and MLA ``{ckv_pages, kr_pages}`` pools.
-    """
-    table = cache["page_table"]            # (B, pages_per_row) int32
-    rows = jnp.arange(table.shape[0])[:, None]
-    page_idx = cols // pt
-    pages_per_row = table.shape[-1]
-    valid = page_idx < pages_per_row
-    phys = table[rows, jnp.minimum(page_idx, pages_per_row - 1)]
-    phys = jnp.where(valid, phys, n_pages)  # (B, S) page ids
-    if "page_ro" in cache:
-        # COW prefix sharing: a page mapped by >1 sequence is
-        # write-protected — the pool manager forks before any legitimate
-        # write reaches one, so a scatter aimed at it means host and device
-        # state disagree; drop it like an overflow write rather than corrupt
-        # the co-holder.  Only the scatter is rerouted — the attention
-        # gather still reads shared pages through the table.
-        ro = cache["page_ro"][jnp.minimum(phys, n_pages - 1)]
-        phys = jnp.where(ro, n_pages, phys)
-    gather_table = table
-    if "page_hot" in cache:
-        # tiered residency: a non-hot page's bytes live in the host tier
-        # (demoted) or are mid-migration — the engine never decodes such a
-        # slot, so a table entry still aimed at one means residency
-        # bookkeeping and device state disagree.  Drop scatters at it like
-        # overflow writes and reroute the gather to the (all-zero,
-        # always-hot) parking page rather than read a physical page the
-        # pool may have re-issued.
-        hot = cache["page_hot"]
-        phys = jnp.where(hot[jnp.minimum(phys, n_pages - 1)], phys, n_pages)
-        gather_table = jnp.where(hot[table], table, n_pages - 1)
-    return phys, cols % pt, gather_table
-
-
-def _paged_write(cache: dict, new: dict, cols: Array):
-    """Scatter each pool's new tokens (``new``: pool name -> (B, S,
-    *feature)) into each row's pages (:func:`_page_routes`) and gather every
-    row's logical view of each pool through its page table.  Returns the
-    new cache and the views, (B, pages·pt, *feature) each, in ``new``'s
-    order.
-
-    A page is ``pt`` rows of one token each, or, in a pool of a narrow
-    feature, rows of several tokens side by side
-    (:func:`repro.serve.disagg.page_shape`); a token's window starts at its
-    row and its lane offset in that row.  A cache that carries a ``layer``
-    index holds its pools stacked over the scanned layers
-    (``transformer.apply_stack`` carries them through the layer scan): the
-    scatter and the gather index the stacked pool at that layer directly,
-    so no layer's pool is sliced out or written back."""
-    lead = (cache["layer"],) if "layer" in cache else ()
-    k = len(lead)
-    first = next(iter(new))
-    pool = cache[first]
-    pt = pool.shape[k + 1] * (pool.shape[-1] // new[first].shape[-1])
-    phys, in_page, gather_table = _page_routes(cache, cols, pool.shape[k], pt)
-    B = cols.shape[0]
-    out, views = dict(cache), []
-    for name, val in new.items():
-        pool, val = cache[name], val.astype(cache[name].dtype)
-        per_row = pool.shape[-1] // val.shape[-1]   # tokens side by side
-        if per_row == 1:
-            pool = pool.at[lead + (phys, in_page)].set(val)
-        else:
-            # element by element, from the token's lane offset in its row
-            # (a window that starts inside the row is not a native scatter)
-            f = val.shape[-1]
-            lanes = (in_page % per_row * f)[..., None] + jnp.arange(f)
-            pool = pool.at[lead + (phys[..., None], (in_page // per_row)[..., None],
-                                   lanes)].set(val)
-        out[name] = pool
-        views.append(pool[lead + (gather_table,)].reshape(B, -1, *val.shape[2:]))
-    return out, views
-
-
 def gqa_attention(
     params: dict,
     x: Array,
@@ -291,12 +210,12 @@ def gqa_attention(
         pos = cache["pos"]  # (B,) int32: per-row current length
         rows = jnp.arange(B)[:, None]
         cols = pos[:, None] + jnp.arange(S)[None, :]
-        if "k_pages" in cache:
+        if paged_cache.is_paged(cache):
             # paged KV: the cache is a physical page pool + per-row page
             # table (the decode-side PagedKVWindow layout).  New tokens
             # scatter into the row's current physical page; attention
             # gathers the row's pages back into a contiguous logical view.
-            new_cache, (ck, cv) = _paged_write(
+            new_cache, (ck, cv) = paged_cache.paged_write(
                 cache, {"k_pages": k, "v_pages": v}, cols)  # (B, pages·pt, KV, hd)
             new_cache["pos"] = pos + S
             ck = logical_constraint(ck, "batch", "kv_seq", "kv_heads", None)
@@ -381,24 +300,6 @@ def gqa_cache_spec(cfg) -> dict:
         "v": ("batch", "kv_seq", "kv_heads", None),
         "pos": ("batch",),
     }
-
-
-def init_paged_gqa_cache(cfg, batch: int, max_seq: int, dtype,
-                         page_tokens: int) -> dict:
-    """Paged-layout GQA cache: a physical page pool + per-row page table.
-
-    The pool holds ``batch · max_seq / page_tokens`` allocatable pages plus
-    one **parking page**; which physical page backs logical block *b* of
-    row *r* is the serving engine's page allocator's decision
-    (``page_table[r, b]``), exactly the indirection a decode-side
-    :class:`repro.serve.paged.PagedKVWindow` pool gives a disaggregated
-    deployment.  One definition of the layout exists —
-    ``repro.serve.disagg.paginate_cache`` — and this constructor delegates
-    to it, so the pool/parking/table invariants cannot drift."""
-    from repro.serve.disagg import paginate_cache
-
-    return paginate_cache(init_gqa_cache(cfg, batch, max_seq, dtype),
-                          page_tokens)
 
 
 # ---------------------------------------------------------------------------
@@ -559,7 +460,7 @@ def mla_attention(
     The KV cache stores only (c_kv: kv_lora, k_rope: qk_rope) per token --
     the compression that makes many-head attention servable -- densely
     (``{c_kv, k_rope, pos}``) or in pages (``{ckv_pages, kr_pages,
-    page_table, ...}``, :func:`repro.serve.disagg.paginate_cache`).  A
+    page_table, ...}``, :func:`paged_cache.paginate_cache`).  A
     one-token step against a cache (decode) attends in the absorbed form
     (:func:`_mla_absorbed`); a prompt, with or without a cache, in the
     expanded form over key blocks (:func:`_mla_blockwise`).
@@ -587,8 +488,8 @@ def mla_attention(
     if cache is not None:
         pos = cache["pos"]  # (B,)
         cols = pos[:, None] + jnp.arange(S)[None, :]
-        if "ckv_pages" in cache:
-            new_cache, (c_all, kr_all) = _paged_write(
+        if paged_cache.is_paged(cache):
+            new_cache, (c_all, kr_all) = paged_cache.paged_write(
                 cache, {"ckv_pages": c_kv, "kr_pages": k_rope}, cols)
         else:
             rows = jnp.arange(B)[:, None]
@@ -637,7 +538,6 @@ def mla_cache_spec(cfg) -> dict:
 
 __all__ = [
     "init_gqa", "gqa_spec", "gqa_attention", "init_gqa_cache", "gqa_cache_spec",
-    "init_paged_gqa_cache",
     "init_mla", "mla_spec", "mla_attention", "mla_softmax_scale",
     "init_mla_cache", "mla_cache_spec",
     "full_attention", "blockwise_attention",

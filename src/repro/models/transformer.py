@@ -23,8 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models import attention, layers, moe as moe_lib, ssm
-from repro.serve.disagg import is_paged, page_pools
+from repro.models import attention, layers, moe as moe_lib, paged_cache, ssm
 from repro.sharding import logical_constraint
 
 Array = jax.Array
@@ -340,34 +339,6 @@ def stack_cache_spec(cfg, plan=None) -> dict:
     return spec
 
 
-def _split_pools(tree):
-    """Split a scanned block cache into (the tree without its page pools,
-    the pools): each paged attention dict's pool leaves
-    (``disagg.page_pools``) go to a tree of their own, and its ``layer``
-    index is dropped.  The pools tree is empty where nothing is paged."""
-    if not isinstance(tree, dict):
-        return tree, {}
-    if is_paged(tree):
-        names = set(page_pools(tree).values())
-        return ({k: v for k, v in tree.items() if k not in names | {"layer"}},
-                {k: tree[k] for k in names})
-    rest, pools = {}, {}
-    for key, val in tree.items():
-        rest[key], sub = _split_pools(val)
-        if sub:
-            pools[key] = sub
-    return rest, pools
-
-
-def _join_pools(tree, pools, layer=None):
-    """The inverse of :func:`_split_pools`: each paged dict gets its pools
-    back, and with ``layer`` the index of its layer in them."""
-    if is_paged(tree):
-        return dict(tree, **pools) if layer is None else dict(tree, **pools, layer=layer)
-    return {key: _join_pools(val, pools[key], layer) if key in pools else val
-            for key, val in tree.items()}
-
-
 def apply_stack(
     params: dict,
     x: Array,
@@ -424,18 +395,19 @@ def apply_stack(
             # a paged cache's stacked page pools ride the carry, and each
             # layer writes and reads them at its index, in place; the rest
             # of the cache is scanned as xs -> ys
-            rest, pools = _split_pools(cache["scan"])
+            rest, pools = paged_cache.split_pools(cache["scan"])
 
             def body(carry, xs):
                 xx, aux, pl = carry
                 bp, bc, i = xs
-                xx, aux, nc = apply_period(xx, aux, bp, _join_pools(bc, pl, i))
-                nc, pl = _split_pools(nc)
+                xx, aux, nc = apply_period(xx, aux, bp,
+                                           paged_cache.join_pools(bc, pl, i))
+                nc, pl = paged_cache.split_pools(nc)
                 return (xx, aux, pl), nc
             (x, aux_total, pools), scanned_cache = lax.scan(
                 body, (x, aux_total, pools),
                 (params["scan"], rest, jnp.arange(count)))
-            new_cache["scan"] = _join_pools(scanned_cache, pools)
+            new_cache["scan"] = paged_cache.join_pools(scanned_cache, pools)
         else:
             def body(carry, bp):
                 xx, aux = carry

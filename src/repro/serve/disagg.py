@@ -33,11 +33,11 @@ the page count of the pushed sequence and ``bell`` is the doorbell flag the
 consumer polls.
 
 The SPMD functions here run inside ``shard_map`` (prefill devices push to
-decode devices over a mesh axis).  The host-side pieces —
-:class:`PageAllocator` and :func:`paginate_cache` — wire the same page-table
-discipline into the single-process :class:`~repro.serve.engine.ServeEngine`
-(``paged_kv=True``), so the engine's KV cache *is* the decode-side pool
-layout a disaggregated deployment would receive pushes into.
+decode devices over a mesh axis).  The single-process
+:class:`~repro.serve.engine.ServeEngine` (``paged_kv=True``) keeps its KV
+cache in the same page-table discipline (``models/paged_cache.py``), so the
+engine's cache *is* the decode-side pool layout a disaggregated deployment
+would receive pushes into.
 
 Run the 8-fake-device round-trip demo (prefill→push→doorbell→admission→
 decode through the handle path) with::
@@ -46,8 +46,6 @@ decode through the handle path) with::
         python -m repro.serve.disagg
 """
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -188,180 +186,6 @@ def pool_stats(pool: PagedKVWindow) -> dict[str, Array]:
 
 
 # ---------------------------------------------------------------------------
-# Host side: the page allocator + paged-cache plumbing for ServeEngine
-# ---------------------------------------------------------------------------
-
-
-class PageAllocator:
-    """Host-side FIFO free-list over the decode pool's physical pages.
-
-    FIFO (not LIFO) so freed pages are reused as late as possible — maximum
-    pressure on the stale-handle guarantee in tests and the most grace for
-    in-flight transfers in a real deployment."""
-
-    def __init__(self, n_pages: int):
-        self.n_pages = n_pages
-        self._free = list(range(n_pages))
-
-    def alloc(self, n: int) -> list[int]:
-        if n > len(self._free):
-            raise RuntimeError(
-                f"KV page pool exhausted: need {n} pages, "
-                f"{len(self._free)}/{self.n_pages} free")
-        pages, self._free = self._free[:n], self._free[n:]
-        return pages
-
-    def free(self, pages) -> None:
-        self._free.extend(pages)
-
-    @property
-    def n_free(self) -> int:
-        return len(self._free)
-
-
-#: each dense cache kind's leaves and the page pools ``paginate_cache``
-#: turns them into: GQA keys and values, and MLA's latent pair
-PAGED_LEAVES = {
-    frozenset({"k", "v", "pos"}): {"k": "k_pages", "v": "v_pages"},
-    frozenset({"c_kv", "k_rope", "pos"}): {"c_kv": "ckv_pages",
-                                           "k_rope": "kr_pages"},
-}
-
-
-def is_paged(d) -> bool:
-    """Whether ``d`` is a paged self-attention cache (either kind)."""
-    return isinstance(d, dict) and "page_table" in d
-
-
-def page_pools(d) -> dict:
-    """``{dense leaf: page pool}`` of a paged cache dict, in pool order."""
-    for leaves in PAGED_LEAVES.values():
-        if all(pool in d for pool in leaves.values()):
-            return leaves
-    raise ValueError(f"no page pools in a cache dict with keys {sorted(d)}")
-
-
-def page_axis(d) -> int:
-    """The axis of a paged dict's pools that indexes pages: 0, or 1 under
-    the leading (layers) dim of a scanned stack."""
-    return d["page_table"].ndim - 2
-
-
-def parking_page(d) -> int:
-    """The id of a paged dict's parking page (the last page)."""
-    return d["page_ro"].shape[-1] - 1
-
-
-#: lanes of a TPU vector register, the minor tile of its memory layout
-LANES = 128
-
-
-def page_shape(page_tokens: int, feature: tuple) -> tuple:
-    """The shape of one page of a pool of ``feature``-shaped tokens:
-    ``(page_tokens, *feature)``, or, for a 1-D feature that divides the
-    lanes (MLA's 64-wide rope key), rows of ``LANES`` that each hold
-    several tokens side by side.  A TPU lays a ``(…, pages, pt, 64)`` pool
-    out with its pages minor, so every paged write or gather would first
-    copy the whole pool into another layout; rows of ``LANES`` keep each
-    page whole in its own tiles."""
-    if (len(feature) == 1 and LANES % feature[0] == 0
-            and page_tokens * feature[0] % LANES == 0):
-        return (page_tokens * feature[0] // LANES, LANES)
-    return (page_tokens, *feature)
-
-
-def paginate_cache(cache, page_tokens: int):
-    """Convert every dense self-attention leaf group of a stack cache -- GQA
-    ``{k, v, pos}`` or MLA's latent ``{c_kv, k_rope, pos}`` -- into the
-    pooled page layout: ``{k_pages, v_pages, ...}`` or ``{ckv_pages,
-    kr_pages, ...}`` beside one ``page_table``, ``page_ro``, ``page_hot``
-    and ``pos``.
-
-    Dense leaves of shape ``(…, B, S, *feature)`` become physical pools
-    of ``B·S/pt`` allocatable pages (:func:`page_shape`) **plus one
-    parking page**; every
-    page-table entry starts pointing at the parking page, and the engine's
-    :class:`PageAllocator` (which hands out ids ``0 … B·S/pt − 1``) wires
-    rows to real pages at slot admission.  The parking page matters: idle
-    and released decode rows still scatter their (discarded) per-step KV
-    through the table, and parking those writes on a page no allocation can
-    ever own is what keeps them from corrupting a live slot's pages.
-    Leaves that are not self-attention KV (cross-attention, SSM state, the
-    step counter, MoE counters) pass through unchanged, so hybrid stacks
-    page only what pages.  Both kinds share the table and the two bit
-    leaves, so the engine's allocator, release, COW fork and tier
-    migration are one code path for both.
-
-    The ``page_ro`` leaf is the pool's per-page write protection: the
-    engine sets it for pages mapped by more than one sequence (COW prefix
-    sharing), and the decode scatter in ``models/attention.py`` drops
-    writes routed at a protected page exactly like overflow writes.  The
-    parking page is never protected.
-
-    The ``page_hot`` leaf is the pool's per-page **residency** bit (the
-    tiered engine clears it for pages demoted to the host tier): the paged
-    gather reroutes table entries at a non-hot page to the parking page and
-    the scatter drops writes at one — defense in depth mirroring
-    ``page_ro``, so a residency-bookkeeping bug reads zeros instead of a
-    reclaimed page's bytes.  Everything starts hot (an untier'd engine
-    never clears it), and the parking page is always hot."""
-    leaves = (PAGED_LEAVES.get(frozenset(cache)) if isinstance(cache, dict)
-              else None)
-    if leaves is not None:
-        first = cache[next(iter(leaves))]
-        lead = cache["pos"].ndim - 1       # a scanned stack's layers dim
-        b, s = first.shape[lead], first.shape[lead + 1]
-        if s % page_tokens:
-            raise ValueError(f"max_seq={s} not divisible by "
-                             f"page_tokens={page_tokens}")
-        pages_per_row = s // page_tokens
-        n_alloc = b * pages_per_row        # the allocator's page ids
-        lead_shape = first.shape[:lead]
-
-        def repage(x):
-            page = page_shape(page_tokens, x.shape[lead + 2:])
-            pool = x.reshape(*lead_shape, n_alloc, *page)
-            park = jnp.zeros((*lead_shape, 1, *page), pool.dtype)
-            return jnp.concatenate([pool, park], axis=lead)
-
-        out = {pool: repage(cache[dense]) for dense, pool in leaves.items()}
-        out.update(
-            page_table=jnp.full((*lead_shape, b, pages_per_row), n_alloc,
-                                jnp.int32),
-            page_ro=jnp.zeros((*lead_shape, n_alloc + 1), bool),
-            page_hot=jnp.ones((*lead_shape, n_alloc + 1), bool),
-            pos=cache["pos"])
-        return out
-    if isinstance(cache, dict):
-        return {key: paginate_cache(val, page_tokens) for key, val in cache.items()}
-    if isinstance(cache, list):
-        return [paginate_cache(val, page_tokens) for val in cache]
-    return cache
-
-
-def park_slot(cache, slot: int):
-    """Point a released slot's page-table rows back at the parking page and
-    rewind its position counter — after this, the slot's idle decode writes
-    land on the parking page and its old (now freed, maybe re-allocated)
-    pages are never touched again."""
-    if isinstance(cache, dict):
-        if is_paged(cache):
-            table, pos = cache["page_table"], cache["pos"]
-            park = parking_page(cache)
-            if table.ndim == 2:
-                table = table.at[slot].set(park)
-                pos = pos.at[slot].set(0)
-            else:
-                table = table.at[:, slot].set(park)
-                pos = pos.at[:, slot].set(0)
-            return dict(cache, page_table=table, pos=pos)
-        return {key: park_slot(val, slot) for key, val in cache.items()}
-    if isinstance(cache, list):
-        return [park_slot(val, slot) for val in cache]
-    return cache
-
-
-# ---------------------------------------------------------------------------
 # The round-trip demo (8 fake devices): prefill→push→doorbell→admit→decode
 # ---------------------------------------------------------------------------
 
@@ -487,15 +311,5 @@ __all__ = [
     "claim_slots",
     "read_doorbell",
     "pool_stats",
-    "PageAllocator",
-    "PAGED_LEAVES",
-    "LANES",
-    "page_shape",
-    "is_paged",
-    "page_pools",
-    "page_axis",
-    "parking_page",
-    "paginate_cache",
-    "park_slot",
     "demo_round_trip",
 ]

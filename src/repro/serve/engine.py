@@ -24,9 +24,10 @@ to the previous monolithic engine — the layers change who decides, not
 what runs.
 
 ``paged_kv=True`` replaces the dense per-slot KV with the **paged pool
-layout** of the disaggregated serving runtime (``repro.serve.disagg``): the
-self-attention cache becomes a physical page pool plus a per-row page
-table — exactly the cache a decode worker owns in a prefill→decode split.
+layout** (``models/paged_cache.py``, which owns it and every operation on
+it): the self-attention cache becomes a physical page pool plus a per-row
+page table — exactly the cache a decode worker owns in a prefill→decode
+split.  The executor's page ops are single calls into that module.
 ``prefix_share=True`` additionally admits new requests onto the pages of a
 live request with a common prompt prefix:
 
@@ -69,8 +70,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import paged_cache
 from repro.models.transformer import moe_counts
-from repro.serve.disagg import is_paged, page_axis, page_pools, parking_page
 from repro.serve.paged import HostKVTier, KVPoolManager
 from repro.serve.scheduler import Scheduler
 
@@ -113,28 +114,6 @@ def _span(name: str, **args):
     return _NO_SPAN
 
 
-def _paged_dicts(tree):
-    """Yield every dict node of a cache tree (to probe for paged leaves)."""
-    if isinstance(tree, dict):
-        yield tree
-        for v in tree.values():
-            yield from _paged_dicts(v)
-    elif isinstance(tree, list):
-        for v in tree:
-            yield from _paged_dicts(v)
-
-
-def _map_paged(cache, fn):
-    """Rebuild a cache tree applying ``fn`` to every paged-attention dict."""
-    if isinstance(cache, dict):
-        if is_paged(cache):
-            return fn(cache)
-        return {k: _map_paged(v, fn) for k, v in cache.items()}
-    if isinstance(cache, list):
-        return [_map_paged(v, fn) for v in cache]
-    return cache
-
-
 def _insert_row(full: Array, one: Array, slot, n_slots: int) -> Array:
     """Scatter a 1-row leaf into the n_slots-row leaf along the batch axis.
 
@@ -171,9 +150,7 @@ def executor_programs(model, *, n_slots: int, max_seq: int, enc_len: int = 0,
     def fresh_cache():
         cache = model.init_cache(n_slots, max_seq, enc_len=enc_len)
         if paged_kv:
-            from repro.serve import disagg
-
-            cache = disagg.paginate_cache(cache, page_tokens)
+            cache = paged_cache.paginate_cache(cache, page_tokens)
         return cache
 
     def decode_step(params, cache, tokens):
@@ -201,8 +178,9 @@ def _insert(full, one, slot, phys_pages, write_ok, n_slots: int):
     scatter through the page table, everything else along the batch
     axis)."""
     if isinstance(full, dict):
-        if is_paged(full):
-            return _insert_paged_attn(full, one, slot, phys_pages, write_ok)
+        if paged_cache.is_paged(full):
+            return paged_cache.insert_prefill(full, one, slot, phys_pages,
+                                              write_ok)
         return {key: _insert(full[key], one[key], slot, phys_pages, write_ok,
                              n_slots)
                 for key in full}
@@ -210,44 +188,6 @@ def _insert(full, one, slot, phys_pages, write_ok, n_slots: int):
         return [_insert(f, o, slot, phys_pages, write_ok, n_slots)
                 for f, o in zip(full, one)]
     return _insert_row(full, one, slot, n_slots)
-
-
-def _insert_paged_attn(full, one, slot, phys_pages, write_ok):
-    """Scatter a dense (1, S, *feature) prefill cache -- GQA K and V, or the
-    latent pair -- into the slot's physical pages and point the slot's
-    page-table row at them.  Pages with ``write_ok=False`` are *shared* —
-    the donor already holds their prefix KV — so their scatter is routed to
-    the parking page while the table still maps them."""
-    dest = jnp.where(write_ok, phys_pages, parking_page(full))
-    ax = page_axis(full)
-
-    def repage_scatter(pool, dense):
-        # page by page, each an in-place dynamic_update_slice of the page
-        # in every layer: a scatter of whole pages made the compiler copy
-        # the pool into another tiling and back, and a scatter of rows ran
-        # 5-7x slower than this loop (TPU v5e: 18.8 against 2.7 ms for
-        # StarCoder2-3B's pools, 31.3 against 5.7 ms for DeepSeek-V2-Lite's)
-        pages = dense.reshape(*dense.shape[:ax], -1, *pool.shape[ax + 1:])
-        pages = pages.astype(pool.dtype)
-
-        def put(j, pool):
-            page = jax.lax.dynamic_slice_in_dim(pages, j, 1, axis=ax)
-            start = (0,) * ax + (dest[j],) + (0,) * (pool.ndim - ax - 1)
-            return jax.lax.dynamic_update_slice(pool, page, start)
-
-        return jax.lax.fori_loop(0, pages.shape[ax], put, pool)
-
-    table, pos = full["page_table"], full["pos"]
-    if table.ndim == 2:
-        table = table.at[slot].set(phys_pages)
-        pos = pos.at[slot].set(one["pos"][0])
-    else:
-        table = table.at[:, slot].set(phys_pages)
-        pos = pos.at[:, slot].set(one["pos"][:, 0])
-    out = dict(full, page_table=table, pos=pos)
-    for dense, pool in page_pools(full).items():
-        out[pool] = repage_scatter(full[pool], one[dense])
-    return out
 
 
 class Executor:
@@ -275,7 +215,7 @@ class Executor:
             model, n_slots=n_slots, max_seq=max_seq, enc_len=enc_len,
             paged_kv=paged_kv, page_tokens=page_tokens)
         self.cache = fresh_cache()
-        if paged_kv and not any(is_paged(d) for d in _paged_dicts(self.cache)):
+        if paged_kv and not paged_cache.pool_leaves(self.cache):
             raise ValueError(
                 f"paged_kv=True but the {model.cfg.family!r} stack has "
                 "no self-attention KV caches to page (SSM caches stay "
@@ -301,7 +241,8 @@ class Executor:
     @property
     def pool_bytes(self) -> int:
         """Bytes of the cache's page pools (0 unpaged)."""
-        return sum(d[pool].nbytes for d, pool, _ in self._pool_leaves())
+        return sum(d[pool].nbytes
+                   for d, pool, _ in paged_cache.pool_leaves(self.cache))
 
     @property
     def decode_pool_aliased_bytes(self) -> int | None:
@@ -345,160 +286,56 @@ class Executor:
                                   experts_hit=int(self.last_counts[1]))
             return argmax
 
-    # -- paged-pool device ops ---------------------------------------------------
+    # -- paged-pool device ops (``models/paged_cache.py``) ----------------------
     def fork_page(self, slot: int, j: int, src: int, dst: int) -> None:
-        """Copy-on-write fork: copy physical page ``src`` → ``dst`` in every
-        paged pool and point this slot's table entry ``j`` at the copy."""
-        def fork(d):
-            lead = (slice(None),) * page_axis(d)
-            out = dict(d)
-            for pool in page_pools(d).values():
-                leaf = d[pool]
-                out[pool] = leaf.at[lead + (dst,)].set(leaf[lead + (src,)])
-            out["page_table"] = d["page_table"].at[lead + (slot, j)].set(dst)
-            out["page_ro"] = d["page_ro"].at[..., dst].set(False)
-            if "page_hot" in d:
-                out["page_hot"] = d["page_hot"].at[..., dst].set(True)
-            return out
-
-        self.cache = _map_paged(self.cache, fork)
+        """Copy-on-write fork of page ``src`` to ``dst`` for ``slot``'s
+        entry ``j`` (:func:`paged_cache.fork_page`)."""
+        self.cache = paged_cache.fork_page(self.cache, slot, j, src, dst)
 
     def set_pages_ro(self, pages, value: bool) -> None:
-        """(Un)write-protect physical pages device-side: decode scatters at
-        an RO page are dropped like overflow writes (defense in depth — the
-        pool manager forks before any legitimate write reaches one)."""
-        idx = jnp.asarray(list(pages), jnp.int32)
-
-        def mark(d):
-            return dict(d, page_ro=d["page_ro"].at[..., idx].set(value))
-
-        self.cache = _map_paged(self.cache, mark)
+        """(Un)write-protect physical pages
+        (:func:`paged_cache.set_pages_ro`)."""
+        self.cache = paged_cache.set_pages_ro(self.cache, pages, value)
 
     def set_pages_hot(self, pages, value: bool) -> None:
-        """Flip physical pages' device-side residency bit.  The tiered
-        engine clears it when a page's bytes leave for the host tier and
-        sets it when fresh pages are wired (admission, promotion, COW
-        fork); ``models/attention.py`` reroutes any gather or scatter still
-        aimed at a non-hot page to the parking page — defense in depth
-        mirroring ``page_ro``."""
-        idx = jnp.asarray(list(pages), jnp.int32)
-
-        def mark(d):
-            if "page_hot" not in d:
-                return d
-            return dict(d, page_hot=d["page_hot"].at[..., idx].set(value))
-
-        self.cache = _map_paged(self.cache, mark)
+        """Set physical pages' residency bit
+        (:func:`paged_cache.set_pages_hot`)."""
+        self.cache = paged_cache.set_pages_hot(self.cache, pages, value)
 
     # -- tiered payload migration -------------------------------------------
-    def _pool_leaves(self):
-        """Every page pool of the cache, with its page axis, in the fixed
-        walk order the payload gather and scatter share."""
-        for d in _paged_dicts(self.cache):
-            if is_paged(d):
-                for pool in page_pools(d).values():
-                    yield d, pool, page_axis(d)
-
     @property
     def page_payload_dtype(self):
-        """Dtype of the concatenated per-page payload (the pools' dtype)."""
-        for d, pool, _ in self._pool_leaves():
-            return d[pool].dtype
-        raise ValueError("no paged pools in this cache")
+        """Dtype of one page's payload
+        (:func:`paged_cache.page_payload_dtype`)."""
+        return paged_cache.page_payload_dtype(self.cache)
 
     @property
     def page_payload_elems(self) -> int:
-        """Elements in one page's full payload: every paged pool's bytes
-        for that page concatenated (K and V, or the latent pair; a
-        scan-stacked pool contributes all its layers), so one host-tier
-        slot round-trips one logical KV page no matter how the stack is
-        laid out."""
-        n = sum(d[pool].size // d[pool].shape[ax]
-                for d, pool, ax in self._pool_leaves())
-        if not n:
-            raise ValueError("no paged pools in this cache")
-        return n
+        """Elements in one page's payload
+        (:func:`paged_cache.page_payload_elems`)."""
+        return paged_cache.page_payload_elems(self.cache)
 
     def gather_page_payloads(self, pages) -> Array:
-        """Read physical pages' full payloads — ``(len(pages),
-        page_payload_elems)`` — in the fixed pool walk order
-        :meth:`scatter_page_payloads` writes them back in.  This is the
-        demotion snapshot: because shared (refcount ≥ 2) pages are never
-        written (the pool forks first), a slot's page list read here is
-        exactly its logical KV state."""
-        pages = list(pages)
-        idx = jnp.asarray(pages, jnp.int32)
-        dt = self.page_payload_dtype
-        parts = [jnp.moveaxis(jnp.take(d[pool], idx, axis=ax), ax, 0)
-                 .reshape(len(pages), -1).astype(dt)
-                 for d, pool, ax in self._pool_leaves()]
-        return jnp.concatenate(parts, axis=1)
+        """Physical pages' payloads, ``(len(pages), page_payload_elems)``
+        (:func:`paged_cache.gather_page_payloads`)."""
+        return paged_cache.gather_page_payloads(self.cache, pages)
 
     def scatter_page_payloads(self, pages, payloads) -> None:
-        """Write promoted payloads back into physical pages — the exact
-        inverse of :meth:`gather_page_payloads` (same walk order, per-leaf
-        dtype restored), so a demote→promote round trip is bit-identical."""
-        pages = list(pages)
-        idx = jnp.asarray(pages, jnp.int32)
-        payloads = jnp.asarray(payloads).reshape(len(pages), -1)
-        cur = [0]
-
-        def put(d):
-            out = dict(d)
-            ax = page_axis(d)
-            for pool in page_pools(d).values():
-                leaf = d[pool]
-                shape = (len(pages),) + leaf.shape[:ax] + leaf.shape[ax + 1:]
-                take = leaf.size // leaf.shape[ax]
-                chunk = payloads[:, cur[0]:cur[0] + take].reshape(shape)
-                out[pool] = leaf.at[(slice(None),) * ax + (idx,)].set(
-                    jnp.moveaxis(chunk.astype(leaf.dtype), 0, ax))
-                cur[0] += take
-            return out
-
-        self.cache = _map_paged(self.cache, put)
+        """Write payloads back into physical pages
+        (:func:`paged_cache.scatter_page_payloads`)."""
+        self.cache = paged_cache.scatter_page_payloads(self.cache, pages,
+                                                       payloads)
 
     def map_slot(self, slot: int, phys_pages, pos: int) -> None:
-        """Point ``slot``'s page-table row at ``phys_pages`` and restore its
-        cache position — how a promoted sequence gets its device identity
-        back after its pages round-tripped through the host tier.
-
-        Restores **both** position counters: the paged dicts' per-row
-        ``pos`` (scatter target + causal mask) and the stack's top-level
-        ``step`` counter (rope positions) — the latter kept advancing while
-        the slot sat cold, since parked rows still ride the batched
-        decode."""
-        phys = jnp.asarray(list(phys_pages), jnp.int32)
-
-        def remap(d):
-            table, p = d["page_table"], d["pos"]
-            if table.ndim == 2:
-                table = table.at[slot].set(phys)
-                p = p.at[slot].set(pos)
-            else:
-                table = table.at[:, slot].set(phys)
-                p = p.at[:, slot].set(pos)
-            return dict(d, page_table=table, pos=p)
-
-        def restep(tree):
-            if isinstance(tree, dict):
-                out = {k: (v if k == "step" else restep(v))
-                       for k, v in tree.items()}
-                if "step" in out and not is_paged(out):
-                    out["step"] = out["step"].at[slot].set(pos)
-                return out
-            if isinstance(tree, list):
-                return [restep(v) for v in tree]
-            return tree
-
-        self.cache = restep(_map_paged(self.cache, remap))
+        """Point ``slot`` at ``phys_pages`` at position ``pos``
+        (:func:`paged_cache.map_slot`)."""
+        self.cache = paged_cache.map_slot(self.cache, slot, phys_pages, pos)
 
     def park(self, slot: int) -> None:
-        """Point a released slot's table rows at the parking page (its idle
-        decode writes must never land on pages a later admission owns)."""
-        from repro.serve import disagg
-
-        self.cache = disagg.park_slot(self.cache, slot)
+        """Point a released slot's table rows at the parking page
+        (:func:`paged_cache.park_slot`): its idle decode writes must never
+        land on pages a later admission owns."""
+        self.cache = paged_cache.park_slot(self.cache, slot)
 
 
 class ServeEngine:

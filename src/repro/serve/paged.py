@@ -507,11 +507,12 @@ class PageTier:
 class KVPoolManager:
     """Tiered physical-page pool: an HBM hot tier + a host-memory cold tier.
 
-    The serving engine's pool layer (``docs/serving_disagg.md``): where
-    :class:`repro.serve.disagg.PageAllocator` hands every sequence exclusive
-    pages, this manager lets sequences with a common prompt prefix *map the
-    same physical page* — a refcount per page, :meth:`share_pages` to map an
-    allocated page into another sequence, and :meth:`cow_write` to fork a
+    The serving engine's pool layer (``docs/serving_disagg.md``): it hands
+    out the physical pages of the cache's page pools
+    (``models/paged_cache.py``) from a FIFO free list, and lets sequences
+    with a common prompt prefix *map the same physical page* — a refcount
+    per page, :meth:`share_pages` to map an allocated page into another
+    sequence, and :meth:`cow_write` to fork a
     shared page the moment a holder needs to write it (vLLM-style COW on the
     paper's memhandle lifetime model: a physical page is a memhandle whose
     exposure outlives any one sequence, and the epoch machinery — not this

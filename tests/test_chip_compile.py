@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.serve.disagg import paginate_cache
+from repro.models.paged_cache import paginate_cache
 from repro.serve.engine import executor_programs
 
 #: HBM the v5e compiler lets one program use ("... of 15.75G hbm").

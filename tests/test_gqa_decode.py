@@ -16,7 +16,7 @@ import pytest
 
 from repro.configs.tiny import tiny_config
 from repro.models import attention, build_model
-from repro.serve.disagg import paginate_cache
+from repro.models.paged_cache import paginate_cache
 
 B, S_MAX, PT, KV, HD = 2, 32, 4, 2, 8
 POS = (5, 17)          # per-row lengths before the step: rows differ

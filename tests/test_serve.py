@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs.tiny import tiny_config
 from repro.models import build_model
+from repro.models.paged_cache import paginate_cache
 from repro.serve.engine import Request, ServeEngine
 
 HERE = os.path.dirname(__file__)
@@ -226,27 +227,20 @@ def test_paged_engine_rejects_archs_without_gqa_kv():
 
 
 def test_init_paged_gqa_cache_matches_paginated_dense():
-    """The standalone paged-cache constructor builds the same layout
-    (parking page included) as paginating a dense cache, and a decode step
-    through it matches the dense decode."""
+    """A decode step through a GQA cache paginated from a fresh dense one,
+    its row 0 wired to real pages, matches the dense decode."""
     from repro.models import attention
-    from repro.serve.disagg import paginate_cache
 
     cfg = tiny_config("qwen3-4b")
     B, S, pt = 2, 16, 4
     dense = attention.init_gqa_cache(cfg, B, S, jnp.float32)
-    via_paginate = paginate_cache(dense, pt)
-    direct = attention.init_paged_gqa_cache(cfg, B, S, jnp.float32, pt)
-    assert {k: v.shape for k, v in direct.items()} == \
-           {k: v.shape for k, v in via_paginate.items()}
-    np.testing.assert_array_equal(direct["page_table"],
-                                  np.asarray(via_paginate["page_table"]))
+    paged = paginate_cache(dense, pt)
     # wire row 0 to real pages and decode one token: paged == dense
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(B, 1, cfg.d_model), jnp.float32)
     params = attention.init_gqa(jax.random.PRNGKey(1), cfg)
-    paged = dict(direct,
-                 page_table=direct["page_table"].at[0].set(
+    paged = dict(paged,
+                 page_table=paged["page_table"].at[0].set(
                      jnp.arange(S // pt)))
     positions = jnp.zeros((B, 1), jnp.int32)
     out_d, _ = attention.gqa_attention(params, x, cfg, positions=positions,
@@ -267,7 +261,7 @@ def test_paged_decode_drops_overflow_writes_like_dense():
     cfg = tiny_config("qwen3-4b")
     B, S, pt = 1, 8, 4
     params = attention.init_gqa(jax.random.PRNGKey(1), cfg)
-    paged = attention.init_paged_gqa_cache(cfg, B, S, jnp.float32, pt)
+    paged = paginate_cache(attention.init_gqa_cache(cfg, B, S, jnp.float32), pt)
     paged = dict(paged,
                  page_table=paged["page_table"].at[0].set(jnp.arange(S // pt)),
                  k_pages=paged["k_pages"] + 3.0,
@@ -282,17 +276,6 @@ def test_paged_decode_drops_overflow_writes_like_dense():
                                   np.asarray(paged["k_pages"]))
     np.testing.assert_array_equal(np.asarray(new["v_pages"]),
                                   np.asarray(paged["v_pages"]))
-
-
-def test_paged_pool_exhaustion_raises():
-    from repro.serve.disagg import PageAllocator
-    alloc = PageAllocator(4)
-    pages = alloc.alloc(3)
-    with pytest.raises(RuntimeError, match="exhausted"):
-        alloc.alloc(2)
-    alloc.free(pages)
-    assert alloc.n_free == 4
-    assert alloc.alloc(4) == [3, 0, 1, 2]   # FIFO reuse: freed pages go last
 
 
 def test_disagg_round_trip_multidevice():
@@ -598,7 +581,7 @@ def test_paged_decode_drops_writes_to_ro_pages(dtype):
     cfg = tiny_config("qwen3-4b")
     B, S, pt = 1, 8, 4
     params = attention.init_gqa(jax.random.PRNGKey(1), cfg)
-    base = attention.init_paged_gqa_cache(cfg, B, S, dtype, pt)
+    base = paginate_cache(attention.init_gqa_cache(cfg, B, S, dtype), pt)
     base = dict(base,
                 page_table=base["page_table"].at[0].set(jnp.arange(S // pt)))
     rng = np.random.RandomState(0)
